@@ -219,4 +219,6 @@ def main(*argv: str) -> int:
 
 
 if __name__ == "__main__":
+    from repro import jaxcache
+    jaxcache.enable()
     sys.exit(main(*sys.argv[1:]))

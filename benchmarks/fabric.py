@@ -29,6 +29,15 @@ check.py):
 Writes/merges its row into BENCH_engine.json (``BENCH_JSON`` env var
 overrides), preserving rows from benchmarks/run.py.
 
+One process per chip: each worker imports JAX, and on a TPU host one
+process holds the chips, so ``spawn_shards`` refuses to start workers
+that would claim a TPU (it decides from ``JAX_PLATFORMS`` and the host's
+devices, never by initialising JAX in this parent).  This benchmark
+therefore runs where its workers get the CPU — ``JAX_PLATFORMS=cpu`` on a
+chip host — and measures the multi-process fabric, not the chip.  Shards
+on chips run in one process, each ``BitmapDB`` pinned to its own device
+(``chip_smoke.py --chips 4``).
+
 Usage: python benchmarks/fabric.py [--sizes 1,2,4,8] [--queries 10000]
 """
 from __future__ import annotations
@@ -268,4 +277,6 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro import jaxcache
+    jaxcache.enable()
     sys.exit(main())

@@ -293,6 +293,9 @@ def bitmap_main(path: str | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    _ensure_src()
+    from repro import jaxcache
+    jaxcache.enable()
     if len(sys.argv) > 1 and sys.argv[1] == "bitmap":
         bitmap_main(sys.argv[2] if len(sys.argv) > 2 else None)
     else:

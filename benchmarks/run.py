@@ -856,4 +856,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro import jaxcache
+    jaxcache.enable()
     main()

@@ -62,4 +62,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import jaxcache
+    jaxcache.enable()
     main()

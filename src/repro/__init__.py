@@ -33,7 +33,7 @@ _DB_EXPORTS = ("BitmapDB", "Schema", "Column", "col", "Result", "open")
 _SERVE_EXPORTS = ("BitmapService", "ServiceConfig")
 
 _SUBMODULES = ("db", "engine", "store", "core", "data", "serve", "kernels",
-               "checkpoint", "compat", "fault", "obs")
+               "checkpoint", "fault", "obs")
 
 __all__ = sorted(_DB_EXPORTS + _SERVE_EXPORTS) + sorted(_SUBMODULES)
 

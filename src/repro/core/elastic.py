@@ -26,8 +26,6 @@ import dataclasses
 import math
 from typing import Sequence
 
-from repro import compat  # noqa: F401  (mesh API shims for jax 0.4.x)
-
 import jax
 
 from repro.core.bic import BICConfig, PaperConfig

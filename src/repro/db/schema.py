@@ -126,6 +126,30 @@ class Column:
         return f"{self.name}∈[{self.edges[i]}, {self.edges[i + 1]})"
 
 
+#: widest integer value range a categorical column encodes through a
+#: lookup table (wider ranges take the per-value path)
+_LUT_SPAN = 1 << 16
+
+
+def _encode_column(c: Column, vals) -> np.ndarray:
+    """One column's values -> key ids.  An integer NumPy array of a
+    categorical column maps through a lookup table over its value range
+    (bulk ingest of millions of rows stays vectorized); everything else
+    maps value by value.  Unknown values raise KeyError either way."""
+    if (isinstance(vals, np.ndarray) and c.kind == CATEGORICAL
+            and vals.dtype.kind in "iu" and vals.size):
+        lo, hi = int(vals.min()), int(vals.max())
+        if hi - lo < _LUT_SPAN:
+            lut = np.array([c._value_keys.get(v, -1)
+                            for v in range(lo, hi + 1)], np.int64)
+            out = lut[vals.astype(np.int64) - lo]
+            bad = np.flatnonzero(out < 0)
+            if bad.size:
+                c.key_of(vals[bad[0]].item())       # raises the KeyError
+            return out
+    return np.asarray([c.key_of(v) for v in vals], np.int64)
+
+
 class Schema:
     """An ordered set of :class:`Column` s sharing one key-row space."""
 
@@ -190,7 +214,9 @@ class Schema:
             for c in self.columns:
                 if c.name not in rows:
                     raise KeyError(f"encode: missing column {c.name!r}")
-                vals = list(rows[c.name])
+                vals = rows[c.name]
+                if not isinstance(vals, np.ndarray):
+                    vals = list(vals)
                 if n is None:
                     n = len(vals)
                 elif len(vals) != n:
@@ -203,7 +229,7 @@ class Schema:
                 raise KeyError(f"encode: unknown columns {sorted(extra)}")
             out = np.empty((n or 0, len(self.columns)), np.int32)
             for j, c in enumerate(self.columns):
-                out[:, j] = [c.key_of(v) for v in cols[c.name]]
+                out[:, j] = _encode_column(c, cols[c.name])
             return out
         rows = list(rows)
         out = np.empty((len(rows), len(self.columns)), np.int32)

@@ -81,7 +81,8 @@ class BitmapDB:
     def __init__(self, schema: Schema | None = None, *,
                  num_keys: int | None = None, path: str | None = None,
                  backend: str = "auto", spill_records: int | None = 4096,
-                 capacity_words: int = 16, _restore: bool = False):
+                 capacity_words: int = 16, device=None,
+                 _restore: bool = False):
         if schema is None and num_keys is None:
             raise ValueError("BitmapDB needs a Schema (or num_keys= for a "
                              "raw key-addressed session)")
@@ -99,6 +100,11 @@ class BitmapDB:
         self.path = path
         m = schema.num_keys if schema is not None else int(num_keys)
         self._keys = jnp.arange(m, dtype=jnp.int32)
+        if device is not None:
+            # pin the session to one device: index creation, the capacity
+            # buffer and every query dispatch follow the committed keys
+            # (one process serving a shard per chip)
+            self._keys = jax.device_put(self._keys, device)
         self._index = None                     # read-only sessions only
         self._counts = np.zeros((m,), np.int64)
         self._plans: dict = {}

@@ -37,30 +37,34 @@ import jax
 import jax.numpy as jnp
 
 from repro.engine import policy
-from repro.kernels import bitmap_ops, ref
+from repro.kernels import bitmap_ops, ops, ref
 
 _U32 = jnp.uint32
 
-#: Fast-memory budget (bytes) one tile of work should fit in: the
-#: augmented index tile plus the (Q, G, P, T) accumulator.  Sized for a
-#: CPU L2/L3 slice; comfortably under a TPU core's ~16 MB VMEM too.
-TILE_BUDGET_BYTES = 4 << 20
+#: VMEM budget (bytes) for one word tile of the Pallas sweep: the
+#: double-buffered augmented-index input tile plus the double-buffered
+#: (Q, T) output tile.  Half of a v5e core's 16 MiB default scoped VMEM
+#: limit, which leaves the rest for the rows the kernel holds live.
+TILE_BUDGET_BYTES = 8 << 20
 
-#: Floor on the tile width (words).  Below this the per-tile bookkeeping
-#: dominates and the sweep degenerates into dispatch overhead.
-MIN_TILE_WORDS = 64
+#: Floor on the tile width (words): one TPU lane tile.  Below this the
+#: per-tile bookkeeping dominates and the sweep degenerates into dispatch
+#: overhead.
+MIN_TILE_WORDS = 128
 
 
-def tile_words(m1: int, qgp: int, nw: int,
+def tile_words(m1: int, q: int, nw: int,
                budget: int = TILE_BUDGET_BYTES) -> int:
-    """Largest power-of-two word-tile width such that one augmented index
-    tile (``m1`` rows) plus the accumulator (``qgp`` rows) fits the fast-
-    memory budget; never below :data:`MIN_TILE_WORDS`, never wider than
-    the (pow2-rounded) row itself."""
-    t = 1
+    """Largest power-of-two word-tile width such that the double-buffered
+    augmented index tile (``m1`` rows) and output tile (``q`` rows), each
+    padded to 8 sublanes, fit the VMEM budget; never below
+    :data:`MIN_TILE_WORDS`, never wider than the (pow2-rounded) row
+    itself."""
+    rows = 2 * (policy.round_up(m1, 8) + policy.round_up(q, 8))
+    t = MIN_TILE_WORDS
     while t < nw:
         t *= 2
-    while t > MIN_TILE_WORDS and (m1 + qgp) * t * 4 > budget:
+    while t > MIN_TILE_WORDS and rows * t * 4 > budget:
         t //= 2
     return t
 
@@ -148,15 +152,11 @@ def run_program(aug: jax.Array, num_records, sels: jax.Array,
     the pure-jnp tiled sweep.  Uncompiled — the batch layer jits (and
     vmaps, for segment stacks) exactly like the per-pass body.
     """
-    if jax.default_backend() == "tpu":
-        m1 = aug.shape[0]
-        q, g, p, _ = sels.shape
-        bn = tile_words(m1, q * g * p, aug.shape[1])
-        rows = bitmap_ops.bulk_program(aug, sels, invs, post, block_n=bn,
-                                       interpret=False)
-    else:
+    if ops.interpret_mode():
         rows = _sweep_jnp(aug, sels, invs, post)
-    return jax.vmap(policy.mask_tail, in_axes=(0, None))(rows, num_records)
+        return jax.vmap(policy.mask_tail, in_axes=(0, None))(rows,
+                                                              num_records)
+    return run_program_pallas(aug, num_records, sels, invs, post)
 
 
 def run_program_pallas(aug: jax.Array, num_records, sels: jax.Array,
@@ -167,11 +167,9 @@ def run_program_pallas(aug: jax.Array, num_records, sels: jax.Array,
     """The Pallas realization, callable explicitly (tests exercise it in
     interpret mode off-TPU; :func:`run_program` routes to it on TPU)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    m1 = aug.shape[0]
-    q, g, p, _ = sels.shape
+        interpret = ops.interpret_mode()
     if block_n is None:
-        block_n = tile_words(m1, q * g * p, aug.shape[1])
+        block_n = tile_words(aug.shape[0], sels.shape[0], aug.shape[1])
     rows = bitmap_ops.bulk_program(aug, sels, invs, post, block_n=block_n,
                                    interpret=interpret)
     return jax.vmap(policy.mask_tail, in_axes=(0, None))(rows, num_records)

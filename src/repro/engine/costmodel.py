@@ -12,7 +12,8 @@ This module owns that measurement and the per-wave decision:
     :func:`save_calibration`, and loaded lazily by :func:`get_calibration`
     (path: ``$REPRO_BITMAP_CALIBRATION`` or
     ``results/bitmap_calibration.json``; conservative per-platform
-    defaults apply until a measurement exists).
+    defaults apply until a measurement exists, and a file measured on
+    another platform is refused).
   * :func:`decide` — given the wave's lowered plans, the packed word
     count, the segment count, and optional :class:`~repro.engine.planner.
     KeyStats`, estimate each candidate backend's wall time
@@ -133,9 +134,16 @@ _DEFAULT_COPY = {"cpu": 1.0e10, "tpu": 8.19e11}
 
 
 def _platform_default() -> Calibration:
+    """The priors for the running platform.  A platform without priors is
+    an error: borrowing another platform's numbers would rank backends by
+    a device that is not there."""
     plat = jax.default_backend()
-    key = plat if plat in _DEFAULTS else "cpu"
-    return Calibration(_DEFAULTS[key], _DEFAULT_COPY[key], plat, "default")
+    if plat not in _DEFAULTS:
+        raise RuntimeError(
+            f"no cost-model priors for platform {plat!r} (have "
+            f"{sorted(_DEFAULTS)}); measure one with "
+            "`python benchmarks/roofline.py bitmap`")
+    return Calibration(_DEFAULTS[plat], _DEFAULT_COPY[plat], plat, "default")
 
 
 def calibration_path() -> str:
@@ -148,16 +156,21 @@ _active: Calibration | None = None
 def get_calibration() -> Calibration:
     """The process-wide calibration: an explicit :func:`set_calibration`
     override, else the persisted measurement at :func:`calibration_path`,
-    else the per-platform defaults."""
+    else the per-platform defaults.  A persisted file that does not parse,
+    or that was measured on another platform than the running one, raises:
+    it is never silently used or replaced."""
     global _active
     if _active is None:
         path = calibration_path()
         if os.path.exists(path):
-            try:
-                with open(path) as f:
-                    _active = Calibration.from_json(f.read())
-            except (ValueError, KeyError, OSError):
-                _active = _platform_default()
+            cal = load_calibration(path)
+            plat = jax.default_backend()
+            if cal.platform != plat:
+                raise RuntimeError(
+                    f"calibration {path} was measured on {cal.platform!r}, "
+                    f"but this process runs on {plat!r}; remove it or "
+                    f"point ${ENV_PATH} at a {plat} measurement")
+            _active = cal
         else:
             _active = _platform_default()
     return _active

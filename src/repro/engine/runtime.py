@@ -50,8 +50,6 @@ import threading
 import time
 from typing import Callable, Iterable, Sequence
 
-from repro import compat  # noqa: F401  (jax.shard_map / mesh shims on 0.4.x)
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,7 +93,9 @@ def multicore_create_index(records: jax.Array, keys: jax.Array,
         per_core, mesh=mesh,
         in_specs=(P(axis, None, None), P()),
         out_specs=P(axis, None, None),
-        check_vma=False)   # pallas_call has no replication rule on jax 0.4.x
+        # the Pallas kernels' out_shape structs carry no `vma` (which mesh
+        # axes the output varies over), which check_vma=True requires
+        check_vma=False)
     out = fn(records, keys)
     return out[:zb] if pad else out
 
@@ -175,7 +175,11 @@ class StreamingIndexer:
         self.keys = jnp.asarray(keys, jnp.int32)
         self.backend = backends.resolve_backend(backend)
         self._cap = max(int(capacity_words), 2)
-        self._buf = jnp.zeros((self.keys.shape[0], self._cap), jnp.uint32)
+        # the buffer lives where the keys were committed (a session pinned
+        # to one device); uncommitted keys leave placement to JAX
+        self._buf = jnp.zeros((self.keys.shape[0], self._cap), jnp.uint32,
+                              device=(self.keys.sharding
+                                      if self.keys.committed else None))
         self._num_records = 0
         self._store = None
         self._flush_records: int | None = None

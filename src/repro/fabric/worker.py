@@ -17,9 +17,17 @@ then builds a :class:`~repro.fabric.client.FabricClient` over them with
   * serves until a ``shutdown`` envelope arrives (the fleet's
     ``close()`` sends one per worker, then joins with a terminate
     fallback so a wedged worker cannot hang the parent).
+
+One process per chip: on a TPU host only one process may hold the chips,
+so N workers that each import JAX would contend for them.
+:func:`spawn_shards` therefore refuses to run where its children would
+claim a TPU (decided from the environment, without importing JAX in the
+parent); one process drives every chip through
+``FabricClient.local`` with each shard's ``BitmapDB`` on its own device.
 """
 from __future__ import annotations
 
+import glob
 import json
 import multiprocessing as mp
 import os
@@ -153,6 +161,36 @@ class ShardFleet:
                 p.join(timeout=5.0)
 
 
+#: a TPU chip on the PCI bus: Google's vendor id, "processing
+#: accelerator" class (Google's virtual NICs share the vendor id)
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_ACCELERATOR_PCI_CLASS = "0x12"
+
+
+def _pci_attr(dev: str, name: str) -> str:
+    try:
+        with open(os.path.join(dev, name)) as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
+def children_would_claim_tpu() -> bool:
+    """Whether a spawned child that imports JAX would try to take a TPU.
+    Decided without touching JAX in this process: the child inherits
+    ``JAX_PLATFORMS`` when it is set; otherwise JAX claims a TPU whenever
+    the host exposes one (``/dev/accel*``, or a Google accelerator on the
+    PCI bus)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "").strip()
+    if platforms:
+        return "tpu" in platforms.lower().split(",")
+    if glob.glob("/dev/accel*"):
+        return True
+    return any(_pci_attr(dev, "vendor") == _GOOGLE_PCI_VENDOR
+               and _pci_attr(dev, "class").startswith(_ACCELERATOR_PCI_CLASS)
+               for dev in glob.glob("/sys/bus/pci/devices/*"))
+
+
 def spawn_shards(num_shards: int, *, schema=None, num_keys=None,
                  store_paths=None, shard_records=None,
                  service_config: dict | None = None,
@@ -162,7 +200,17 @@ def spawn_shards(num_shards: int, *, schema=None, num_keys=None,
     addresses.  ``shard_records`` (optional) is one encoded ``(N, W)``
     int32 array per shard, ingested before the worker reports ready —
     the parent typically produced it with ``ShardMap.partition`` and
-    keeps the matching gid tables for its client."""
+    keeps the matching gid tables for its client.
+
+    Raises RuntimeError where the workers would claim a TPU (see
+    :func:`children_would_claim_tpu`): a chip host runs its shards in one
+    process instead (``FabricClient.local``)."""
+    if children_would_claim_tpu():
+        raise RuntimeError(
+            "spawn_shards starts one JAX process per shard, but on a TPU "
+            "host one process holds the chips; serve the shards from one "
+            "process with FabricClient.local, each BitmapDB on its own "
+            "device (BitmapDB(..., device=jax.devices()[i]))")
     ctx = mp.get_context("spawn")
     schema_text = schema.to_json() if schema is not None else None
     procs, conns = [], []

@@ -6,11 +6,13 @@ With bits packed 32-per-uint32 (see cam_match.py) the TPU analogue is a
 with a 5-round butterfly (Hacker's Delight 7-7), then tiles are permuted.
 No unpack to bytes ever happens, so VMEM/HBM traffic stays at 1 bit/bit.
 
-The butterfly is vectorised across the lane axis: a (32, BC) uint32 block is
-BC independent 32x32 bit tiles, and each round combines a row with its
-partner row (index XOR j) via masked shifts.  Partner selection uses two
-jnp.rolls + a select instead of a sublane gather, which lowers to cheap
-sublane shifts on TPU.
+The tile permutation is word-granular, so it runs as XLA transposes around
+the kernel: the input enters as ``(32, C/32, R/32)`` — row ``i`` of every
+32-row group on the leading axis — and the output leaves as ``(32, C/32,
+R/32)`` with the transposed word ``j`` on the leading axis.  Inside the
+kernel each of the 32 butterfly rows is then a whole lane-dense
+``(BC, BG)`` slab: a round combines slab ``k`` with slab ``k ^ j`` by masked
+shifts, with no sublane shuffles or in-register transposes.
 """
 from __future__ import annotations
 
@@ -35,47 +37,55 @@ _ROUNDS = (
 )
 
 
-def _transpose32(x: jax.Array) -> jax.Array:
-    """Transpose each 32x32 bit tile in a (32, BC) uint32 block (in-bit).
+def _transpose32(rows: list) -> list:
+    """Transpose 32x32 bit tiles held as 32 same-shape uint32 slabs.
 
-    LSB-first convention: output word b bit r == input word r bit b.
+    LSB-first convention: output slab b bit r == input slab r bit b.
     """
-    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    rows = list(rows)
     for j, mi in _ROUNDS:
-        m = jnp.uint32(mi)
-        ju = jnp.uint32(j)
-        is_up = (rows & j) == 0                       # row with index-bit j clear
-        partner = jnp.where(is_up, jnp.roll(x, -j, axis=0), jnp.roll(x, j, axis=0))
-        # up row k   : swap high(x[k]) with low(x[k+j]):  t=((x>>j)^p)&m ; x^=t<<j
-        # down row k+j:                                   t=((p>>j)^x)&m ; x^=t
-        t_up = ((x >> ju) ^ partner) & m
-        t_dn = ((partner >> ju) ^ x) & m
-        x = jnp.where(is_up, x ^ (t_up << ju), x ^ t_dn)
-    return x
+        m = _U32(mi)
+        ju = _U32(j)
+        for k in range(PACK):
+            if k & j:
+                continue
+            t = ((rows[k] >> ju) ^ rows[k + j]) & m
+            rows[k] = rows[k] ^ (t << ju)
+            rows[k + j] = rows[k + j] ^ t
+    return rows
 
 
-def _bit_transpose_kernel(in_ref, out_ref, *, block_c: int):
-    x = in_ref[...]                                   # (32, BC) uint32
-    y = _transpose32(x)                               # y[b, c] = out word for column c, bit b
-    # Output row within the block is c*32 + b  ->  (BC, 32) -> (BC*32, 1).
-    out_ref[...] = y.T.reshape(block_c * PACK, 1)
+def _bit_transpose_kernel(in_ref, out_ref):
+    rows = _transpose32([in_ref[i] for i in range(PACK)])   # 32 x (BC, BG)
+    for j in range(PACK):
+        out_ref[j] = rows[j]
 
 
-@functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
-def bit_transpose(packed: jax.Array, *, block_c: int = 64,
-                  interpret: bool = True) -> jax.Array:
+@functools.partial(jax.jit,
+                   static_argnames=("block_c", "block_g", "interpret"))
+def bit_transpose(packed: jax.Array, *, block_c: int, interpret: bool,
+                  block_g: int | None = None) -> jax.Array:
     """Packed (R, C/32) uint32 -> packed (C, R/32) uint32.
 
-    R % 32 == 0 and (C/32) % block_c == 0 (ops.py pads arbitrary shapes).
+    R % 32 == 0, (C/32) % block_c == 0 and (R/32) % block_g == 0
+    (``block_g=None`` takes the whole record-word axis; ops.py pads
+    arbitrary shapes and chooses ``interpret`` from the platform).
     """
     R, Cw = packed.shape
     assert R % PACK == 0 and Cw % block_c == 0
-    grid = (R // PACK, Cw // block_c)
-    return pl.pallas_call(
-        functools.partial(_bit_transpose_kernel, block_c=block_c),
-        grid=grid,
-        in_specs=[pl.BlockSpec((PACK, block_c), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((block_c * PACK, 1), lambda i, j: (j, i)),
-        out_shape=jax.ShapeDtypeStruct((Cw * PACK, R // PACK), _U32),
+    Rw = R // PACK
+    block_g = Rw if block_g is None else block_g
+    assert Rw % block_g == 0
+    # x[i, cw, g] = packed[g*32 + i, cw]
+    x = packed.astype(_U32).reshape(Rw, PACK, Cw).transpose(1, 2, 0)
+    spec = pl.BlockSpec((PACK, block_c, block_g), lambda c, g: (0, c, g))
+    y = pl.pallas_call(
+        _bit_transpose_kernel,
+        grid=(Cw // block_c, Rw // block_g),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((PACK, Cw, Rw), _U32),
         interpret=interpret,
-    )(packed.astype(_U32))
+    )(x)
+    # y[j, cw, g] is output row cw*32 + j, word g
+    return y.transpose(1, 0, 2).reshape(Cw * PACK, Rw)

@@ -2,15 +2,21 @@
 
 The ASIC's CAM compares one key per cycle against a 32-word record held in
 match-line registers.  On TPU the analogue of the parallel match lines is the
-VPU lane grid: we tile BN records x BM keys into VMEM, broadcast each key
-across lanes and OR-reduce the per-word equality over the record-word axis.
-Match bits never leave VMEM unpacked — they are packed 32-per-uint32 before
-the store, which is the TPU analogue of the paper's register-file buffer
-(and cuts HBM write traffic by 32x).
+VPU lane grid: we tile BN records x BM keys into VMEM, broadcast each record
+word across lanes and OR-reduce the per-word equality over the record-word
+axis.  Match bits never leave VMEM unpacked — they are packed 32-per-uint32
+before the store, which is the TPU analogue of the paper's register-file
+buffer (and cuts HBM write traffic by 32x).
 
-Block shapes: records (BN, W) int32, keys (BM,) int32 -> out (BN, BM/32) u32.
-BM is a multiple of 32; the lane dim of the output block is BM/32 so BM=4096
-gives a 128-lane-aligned store.
+Packing without a lane reshape: the keys enter bit-major, ``keys_t[b, 0, j]
+= keys[j*32 + b]``, so bit plane ``b`` of every output word is one
+lane-dense ``(BN, BM/32)`` compare-and-OR against key row ``b`` — the pack
+is a shift-OR into the accumulator, never a split of the lane axis.
+
+Block shapes: records (BN, W) int32, keys_t (32, 1, BM/32) int32 -> out
+(BN, BM/32) u32.  On TPU the lane dim of the output block (BM/32) must be a
+multiple of 128 or the whole key-word axis; :func:`repro.kernels.ops
+.cam_match` picks such blocks.
 """
 from __future__ import annotations
 
@@ -24,50 +30,43 @@ PACK = 32
 _U32 = jnp.uint32
 
 
-def _cam_match_kernel(records_ref, keys_ref, out_ref, *, block_m: int):
+def _cam_match_kernel(records_ref, keys_ref, out_ref):
     """One (BN records) x (BM keys) tile."""
-    records = records_ref[...]                       # (BN, W) int32
-    keys = keys_ref[...]                             # (BM,)  int32
-    bn, w = records.shape
+    bn, w = records_ref.shape
+    bmw = out_ref.shape[1]
 
-    # (BN, BM) match matrix: OR over the record-word axis of per-word equality.
-    # Loop over W (small: 32 in the paper) to keep the VMEM working set at
-    # BN x BM bits rather than BN x BM x W.
-    def body(i, acc):
-        word = jax.lax.dynamic_slice_in_dim(records, i, 1, axis=1)  # (BN, 1)
-        return acc | (word == keys[None, :])
+    def bit_plane(b, acc):
+        key_row = keys_ref[b]                        # (1, BM/32) int32
+        match = jnp.zeros((bn, bmw), jnp.bool_)
+        for i in range(w):                           # W is static: unrolled
+            match = match | (records_ref[:, i:i + 1] == key_row)
+        return acc | jnp.where(match, _U32(1) << b.astype(_U32), _U32(0))
 
-    match = jax.lax.fori_loop(
-        0, w, body, jnp.zeros((bn, block_m), dtype=jnp.bool_))
-
-    # Pack along the key axis, LSB-first: (BN, BM/32) uint32.
-    m = match.astype(_U32).reshape(bn, block_m // PACK, PACK)
-    weights = (_U32(1) << jnp.arange(PACK, dtype=_U32))
-    out_ref[...] = (m * weights[None, None, :]).sum(axis=-1).astype(_U32)
+    out_ref[...] = jax.lax.fori_loop(0, PACK, bit_plane,
+                                     jnp.zeros((bn, bmw), _U32))
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m", "interpret"))
 def cam_match(records: jax.Array, keys: jax.Array, *,
-              block_n: int = 256, block_m: int = 1024,
-              interpret: bool = True) -> jax.Array:
+              block_n: int, block_m: int, interpret: bool) -> jax.Array:
     """records (N, W) int32, keys (M,) int32 -> packed (N, M/32) uint32.
 
     N % block_n == 0, M % block_m == 0, block_m % 32 == 0 (wrappers in
-    ops.py pad arbitrary shapes).
+    ops.py pad arbitrary shapes and choose ``interpret`` from the platform).
     """
     N, W = records.shape
     (M,) = keys.shape
     assert M % block_m == 0 and N % block_n == 0 and block_m % PACK == 0
-
-    grid = (N // block_n, M // block_m)
+    keys_t = keys.astype(jnp.int32).reshape(M // PACK, PACK).T[:, None, :]
+    bmw = block_m // PACK
     return pl.pallas_call(
-        functools.partial(_cam_match_kernel, block_m=block_m),
-        grid=grid,
+        _cam_match_kernel,
+        grid=(N // block_n, M // block_m),
         in_specs=[
             pl.BlockSpec((block_n, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_m,), lambda i, j: (j,)),
+            pl.BlockSpec((PACK, 1, bmw), lambda i, j: (0, 0, j)),
         ],
-        out_specs=pl.BlockSpec((block_n, block_m // PACK), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_n, bmw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, M // PACK), _U32),
         interpret=interpret,
-    )(records.astype(jnp.int32), keys.astype(jnp.int32))
+    )(records.astype(jnp.int32), keys_t)

@@ -1,9 +1,12 @@
 """Public entry points for the BIC Pallas kernels.
 
 These wrappers accept arbitrary shapes (padding to kernel tile multiples),
-pick sane block sizes, and auto-select interpret mode: on CPU the kernels
-run through the Pallas interpreter (bit-exact, used by the test suite); on
-TPU they compile to Mosaic.  ``ref.py`` holds the pure-jnp oracles.
+pick block sizes that satisfy the TPU's (8, 128) tiling rule, and choose
+interpret mode in one place (:func:`interpret_mode`): on TPU the kernels
+compile to Mosaic; on any other platform they run through the Pallas
+interpreter (bit-exact, used by the test suite).  The raw kernels take
+``interpret`` as a required argument.  ``ref.py`` holds the pure-jnp
+oracles.
 """
 from __future__ import annotations
 
@@ -22,8 +25,10 @@ from repro.kernels.ref import PACK, pad_keys, pad_records
 from repro.kernels.ref import round_up as _round_up
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted: never on a TPU, always
+    elsewhere.  The one place that decision is made."""
+    return jax.default_backend() != "tpu"
 
 
 def _pick_block(total: int, preferred: int, multiple: int) -> int:
@@ -35,6 +40,15 @@ def _pick_block(total: int, preferred: int, multiple: int) -> int:
     return b
 
 
+def _tiled_block(total: int, block: int) -> tuple[int, int]:
+    """(block, padded total) for an axis the TPU tiles: the whole axis when
+    it fits in one ``block`` (a full-extent block is always legal), else
+    ``block`` — a multiple of the tile — with the axis padded to fit."""
+    if total <= block:
+        return max(total, 1), total
+    return block, _round_up(total, block)
+
+
 def cam_match(records: jax.Array, keys: jax.Array, *,
               interpret: bool | None = None) -> jax.Array:
     """records (N, W) int, keys (M,) int -> packed (N, ceil(M/32)) uint32.
@@ -44,11 +58,12 @@ def cam_match(records: jax.Array, keys: jax.Array, *,
     (sentinel differs from the record pad sentinel).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     N, W = records.shape
     (M,) = keys.shape
-    Mp = _round_up(M, PACK)
-    block_m = _pick_block(Mp, 1024, PACK)
+    # the output's lane axis is the key-word axis: whole, or 128-word blocks
+    block_mw, mw = _tiled_block(-(-M // PACK), 128)
+    block_m, Mp = block_mw * PACK, mw * PACK
     block_n = _pick_block(_round_up(N, 8), 256, 8)
     Np = _round_up(N, block_n)
     rec = pad_records(records, Np)
@@ -61,12 +76,16 @@ def cam_match(records: jax.Array, keys: jax.Array, *,
 def transpose(packed: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Packed (R, Cw) uint32 -> (Cw*32, ceil(R/32)) uint32 (zero-padded R)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     R, Cw = packed.shape
-    Rp = _round_up(R, PACK)
-    block_c = _pick_block(Cw, 64, 1)
-    x = jnp.pad(packed.astype(jnp.uint32), ((0, Rp - R), (0, 0)))
-    return _bt.bit_transpose(x, block_c=block_c, interpret=interpret)
+    rw = -(-R // PACK)
+    block_c, cwp = _tiled_block(Cw, 8)          # sublane axis of the slabs
+    block_g, rwp = _tiled_block(rw, 256)        # lane axis of the slabs
+    x = jnp.pad(packed.astype(jnp.uint32),
+                ((0, rwp * PACK - R), (0, cwp - Cw)))
+    out = _bt.bit_transpose(x, block_c=block_c, block_g=block_g,
+                            interpret=interpret)
+    return out[:Cw * PACK, :rw]
 
 
 def query(rows: jax.Array, invert: jax.Array, *,
@@ -80,10 +99,9 @@ def query(rows: jax.Array, invert: jax.Array, *,
     forces padded result words to 0 regardless of inversions.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     K, Nw = rows.shape
-    block_n = _pick_block(_round_up(Nw, 8), 2048, 8)
-    Nwp = _round_up(Nw, block_n)
+    block_n, Nwp = _tiled_block(Nw, 2048)
     pad_cols = Nwp - Nw
     r = jnp.pad(rows.astype(jnp.uint32), ((0, 0), (0, pad_cols)))
     inv = invert.astype(jnp.int32)

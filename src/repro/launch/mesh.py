@@ -4,8 +4,6 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax use.
 """
 from __future__ import annotations
 
-from repro import compat  # noqa: F401  (AxisType / make_mesh shims, jax 0.4.x)
-
 import jax
 from jax.sharding import AxisType
 

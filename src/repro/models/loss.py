@@ -18,8 +18,6 @@ from __future__ import annotations
 
 import functools
 
-from repro import compat  # noqa: F401  (get_abstract_mesh / shard_map shims)
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P

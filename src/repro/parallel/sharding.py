@@ -21,8 +21,6 @@ from __future__ import annotations
 import threading
 from typing import Sequence
 
-from repro import compat  # noqa: F401  (get_abstract_mesh shim, jax 0.4.x)
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P

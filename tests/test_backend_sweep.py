@@ -11,6 +11,7 @@ cutoff, decision memoization/factoring/stacking, and the backend-keyed
 service warmup (an ``auto`` session pre-compiles every candidate backend
 so a mid-traffic cost-model switch never stalls on jit).
 """
+import dataclasses
 import json
 import os
 
@@ -212,6 +213,39 @@ def test_calibration_env_path_and_reset(tmp_path, monkeypatch):
     finally:
         monkeypatch.delenv(costmodel.ENV_PATH)
         costmodel.set_calibration(None)
+
+
+def test_calibration_from_another_platform_is_refused(tmp_path, monkeypatch):
+    p = str(tmp_path / "cal.json")
+    foreign = dataclasses.replace(_cal(), platform="tpu")
+    costmodel.save_calibration(foreign, p)
+    monkeypatch.setenv(costmodel.ENV_PATH, p)
+    costmodel.set_calibration(None)
+    try:
+        with pytest.raises(RuntimeError, match="measured on 'tpu'"):
+            costmodel.get_calibration()
+    finally:
+        monkeypatch.delenv(costmodel.ENV_PATH)
+        costmodel.set_calibration(None)
+
+
+def test_unparseable_calibration_raises(tmp_path, monkeypatch):
+    p = tmp_path / "cal.json"
+    p.write_text("{not json")
+    monkeypatch.setenv(costmodel.ENV_PATH, str(p))
+    costmodel.set_calibration(None)
+    try:
+        with pytest.raises(ValueError):
+            costmodel.get_calibration()
+    finally:
+        monkeypatch.delenv(costmodel.ENV_PATH)
+        costmodel.set_calibration(None)
+
+
+def test_platform_without_priors_raises(monkeypatch):
+    monkeypatch.setattr(costmodel.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no cost-model priors"):
+        costmodel._platform_default()
 
 
 def test_candidates_cutoff_drops_interpreted_pallas():

@@ -121,6 +121,40 @@ def test_schema_encode_column_and_row_major():
         s.encode({"city": ["Atlantis"], "temp": [5.0], "tag": ["ok"]})
 
 
+@pytest.mark.parametrize("dtype", [np.uint8, np.int16, np.int64])
+def test_schema_encode_integer_arrays_match_per_value_path(dtype):
+    """Integer NumPy columns of a categorical encode through a lookup
+    table: same key ids as value-by-value encoding, same KeyError on a
+    value the column lacks."""
+    s = Schema([Column.categorical("a", [3, 5, 7, 11]),
+                Column.categorical("b", range(-2, 6))])
+    rng = np.random.default_rng(4)
+    a = rng.choice([3, 5, 7, 11], 500).astype(dtype)
+    b = rng.integers(0 if dtype == np.uint8 else -2, 6, 500).astype(dtype)
+    fast = s.encode({"a": a, "b": b})
+    slow = s.encode({"a": a.tolist(), "b": b.tolist()})
+    np.testing.assert_array_equal(fast, slow)
+    with pytest.raises(KeyError, match="has no value 4"):
+        s.encode({"a": np.array([3, 4, 5], dtype), "b": b[:3]})
+
+
+def test_session_pinned_to_a_device_answers_the_same():
+    """``device=`` commits the session's index to that device; answers
+    equal an unpinned session's."""
+    import jax
+    dev = jax.devices()[0]
+    rows = _weather_rows(np.random.default_rng(2), 300)
+    pinned = BitmapDB(_weather_schema(), device=dev)
+    plain = BitmapDB(_weather_schema())
+    pinned.append(rows)
+    plain.append(rows)
+    buf, _ = pinned.indexer.view()
+    assert buf.committed and buf.devices() == {dev}
+    q = (col("city") == "SF") & ~(col("tag") == "dup")
+    assert pinned.query(q).count == plain.query(q).count
+    np.testing.assert_array_equal(pinned.query(q).ids, plain.query(q).ids)
+
+
 def test_schema_validation_errors():
     with pytest.raises(ValueError, match="duplicate column"):
         Schema([Column.categorical("a", [1]), Column.categorical("a", [2])])

@@ -636,7 +636,6 @@ def test_run_tick_serves_query_batch_against_tick_index():
 
 _NON_DIVISIBLE_SCRIPT = """
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from repro.engine.runtime import multicore_create_index
 from repro.core.bic import BICCore, BICConfig
 assert len(jax.devices()) == 4, jax.devices()

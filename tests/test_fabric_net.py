@@ -390,3 +390,17 @@ def test_multiprocess_shard_workers_end_to_end(tmp_path):
             fc.close()
     for p in fleet.procs:
         assert not p.is_alive()
+
+
+@pytest.mark.parametrize("platforms,claims", [
+    ("tpu", True), ("cpu", False), ("cpu,tpu", True)])
+def test_spawn_shards_refuses_workers_that_would_claim_a_tpu(
+        monkeypatch, platforms, claims):
+    """One process per chip: workers whose inherited JAX_PLATFORMS names
+    the TPU are refused before any process starts."""
+    from repro.fabric import worker
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    assert worker.children_would_claim_tpu() is claims
+    if claims:
+        with pytest.raises(RuntimeError, match="FabricClient.local"):
+            worker.spawn_shards(2, schema=_schema())

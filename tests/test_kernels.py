@@ -25,7 +25,7 @@ RNG = np.random.default_rng(42)
 def test_cam_match_kernel_shapes(n, w, m, bn, bm):
     records = jnp.asarray(RNG.integers(0, 256, (n, w), dtype=np.int32))
     keys = jnp.asarray(RNG.integers(0, 256, (m,), dtype=np.int32))
-    got = cam_match(records, keys, block_n=bn, block_m=bm)
+    got = cam_match(records, keys, block_n=bn, block_m=bm, interpret=True)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(ref.cam_match(records, keys)))
 
@@ -54,7 +54,7 @@ def test_cam_match_odd_shapes_padding():
 ])
 def test_bit_transpose_kernel(r, cw, bc):
     x = jnp.asarray(RNG.integers(0, 2 ** 32, (r, cw), dtype=np.uint32))
-    got = bit_transpose(x, block_c=bc)
+    got = bit_transpose(x, block_c=bc, interpret=True)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(ref.bit_transpose(x)))
 
@@ -64,7 +64,7 @@ def test_bit_transpose_kernel(r, cw, bc):
 def test_bitmap_query_kernel(k, nw, bn):
     rows = jnp.asarray(RNG.integers(0, 2 ** 32, (k, nw), dtype=np.uint32))
     inv = jnp.asarray(RNG.integers(0, 2, (k,), dtype=np.int32))
-    res, cnt = bitmap_query(rows, inv, block_n=bn)
+    res, cnt = bitmap_query(rows, inv, block_n=bn, interpret=True)
     wres, wcnt = ref.bitmap_query(rows, inv)
     np.testing.assert_array_equal(np.asarray(res), np.asarray(wres))
     assert int(cnt) == int(wcnt)

@@ -18,7 +18,7 @@ _DTYPES = [np.uint32, np.int32, np.float32, np.uint8]
 
 
 @settings(max_examples=25, deadline=None)
-@given(st.integers(1, 3), st.integers(0, 2 ** 31 - 1))
+@given(n_arrays=st.integers(1, 3), seed=st.integers(0, 2 ** 31 - 1))
 def test_array_file_roundtrip_property(n_arrays, seed, tmp_path_factory):
     """Property: write_array_file . read_array_file is the identity on
     arbitrary named array sets (dtype, shape, and bytes all survive)."""
@@ -42,8 +42,8 @@ def test_array_file_roundtrip_property(n_arrays, seed, tmp_path_factory):
 
 
 @settings(max_examples=15, deadline=None)
-@given(st.lists(st.integers(1, 60), min_size=1, max_size=6),
-       st.integers(1, 80), st.integers(0, 2 ** 31 - 1))
+@given(block_sizes=st.lists(st.integers(1, 60), min_size=1, max_size=6),
+       flush=st.integers(1, 80), seed=st.integers(0, 2 ** 31 - 1))
 def test_spill_recover_roundtrip_property(block_sizes, flush, seed,
                                           tmp_path_factory):
     """Property: for ANY block-size stream and ANY flush threshold, a

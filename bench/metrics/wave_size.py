@@ -1,0 +1,12 @@
+"""Front end: mean number of queries coalesced into one wave (``size`` of
+the ``coalesce`` spans)."""
+LAYER = "front end (serve/service.py)"
+UNIT = "queries"
+MOVES = "p99_ms"
+
+
+def read(ctx):
+    spans = ctx.spans_named("coalesce")
+    if not spans:
+        return None
+    return sum(s.attrs["size"] for s in spans) / len(spans)

@@ -45,11 +45,16 @@ from repro.db import expr as expr_mod
 from repro.db.result import LazyBatch, Result, ResultBatch
 from repro.db.schema import Schema
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import maybe_span
 from repro.engine import (backends, batch as engine_batch, costmodel,
                           planner, policy)
 from repro.engine.runtime import StreamingIndexer
 
 SCHEMA_FILE = "SCHEMA.json"
+
+_INGEST_READBACKS = obs_metrics.GLOBAL.counter(
+    "db_ingest_readbacks_total",
+    "blocking device-to-host reads made by append_encoded")
 
 
 def include_exclude_pred(include: Sequence[int] = (),
@@ -258,19 +263,39 @@ class BitmapDB:
 
     def append_encoded(self, records) -> int:
         """Stream pre-encoded key-word records (N, W): each int word is a
-        global key id (words outside [0, num_keys) match no key)."""
+        global key id (words outside [0, num_keys) match no key).
+
+        Traced, a block is one ``ingest.append`` span whose children run
+        in order: ``ingest.upload`` (host-to-device copy),
+        ``ingest.create`` (index creation dispatched), ``ingest.splice``
+        (WAL, grow, splice, spill), ``ingest.wait`` (the device finishes
+        the block) and ``ingest.readback`` (the block's popcount read)."""
         if self._si is None:
             raise RuntimeError("read-only session (from_index) — open a "
                                "BitmapDB with a schema/path to ingest")
-        records = jnp.asarray(records, jnp.int32)
-        if records.ndim != 2:
-            raise ValueError(f"records must be (N, W), got "
-                             f"{records.shape}")
-        if records.shape[0]:
-            block = backends.get_backend(self._create_backend).create_index(
-                records, self._keys)
-            self._si.append_indexed(records, block)
-            self._counts += _popcounts(block)
+        shape = np.shape(records)
+        if len(shape) != 2:
+            raise ValueError(f"records must be (N, W), got {shape}")
+        if not shape[0]:
+            return self.num_records
+        with maybe_span("ingest.append", records=shape[0],
+                        backend=self._create_backend):
+            with maybe_span("ingest.upload") as sp:
+                records = jnp.asarray(records, jnp.int32)
+                if sp is not None:
+                    # the copy returns before its DMA ends: traced, the
+                    # span waits for it, so the copy is not timed as wait
+                    records.block_until_ready()
+            with maybe_span("ingest.create"):
+                block = backends.get_backend(
+                    self._create_backend).create_index(records, self._keys)
+            with maybe_span("ingest.splice"):
+                self._si.append_indexed(records, block)
+            with maybe_span("ingest.wait"):
+                block.block_until_ready()
+            with maybe_span("ingest.readback"):
+                self._counts += _popcounts(block)
+                _INGEST_READBACKS.inc()
         return self.num_records
 
     # ----------------------------------------------------------- durability

@@ -33,6 +33,9 @@ Span taxonomy (the contract ARCHITECTURE.md documents)::
     maintenance.<kind>   one spill/compact/gc/scrub task
     store.*              segment prepare/commit/merge/scrub/gc/repair
     spill.*              indexer-side two-phase spill
+    ingest.*             one append_encoded call (attrs: records, backend)
+                         and its children upload, create, splice, wait,
+                         readback, in that order         (per block)
     fault.<kind>         zero-duration event where an injected fault hit
 
 Stdlib-only: importable from the very bottom of the stack (the fault

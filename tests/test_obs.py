@@ -13,6 +13,7 @@ sums back to the scheduler's energy total; `metrics()` / `health()` /
 faults land as events inside the span they interrupted; and the
 disabled path records nothing.
 """
+import itertools
 import json
 import threading
 
@@ -424,12 +425,64 @@ def test_maintenance_task_chains_to_submitter_context(installed_tracer,
     spans = tracer.spans()
     maint = [s for s in spans if s.name.startswith("maintenance.")]
     assert maint                                # spills ran in background
-    ingest = {s.span_id: s for s in spans if s.name == "ingest"}
-    # the background task's span is parented to the ingest span that
-    # scheduled it (captured at submit time, crossed the worker thread)
-    assert any(s.parent_id in ingest for s in maint)
+    by_id = {s.span_id: s for s in spans}
+    ingest = {s.span_id for s in spans if s.name == "ingest"}
+    # the background task's span is parented to the span that scheduled
+    # it (captured at submit time, crossed the worker thread): the
+    # append's splice, which chains up to the caller's ingest span
+    chained = [s for s in maint if s.parent_id in by_id
+               and by_id[s.parent_id].name == "ingest.splice"]
+    assert chained
+    for s in chained:
+        append = by_id[by_id[s.parent_id].parent_id]
+        assert append.name == "ingest.append"
+        assert append.parent_id in ingest
     assert any(s.name.startswith("store.") or s.name.startswith("spill")
                for s in spans)
+
+
+INGEST_CHILDREN = ("ingest.upload", "ingest.create", "ingest.splice",
+                   "ingest.wait", "ingest.readback")
+
+
+@pytest.mark.parametrize("rows,traced", [(512, True), (0, True),
+                                         (512, False)],
+                         ids=["one-block", "empty-block", "tracer-off"])
+def test_append_encoded_span_tree(rows, traced):
+    """One ``append_encoded`` with records is one ``ingest.append`` whose
+    five children follow one another inside it, in order; an empty block
+    records nothing; the readback counter counts whether or not a tracer
+    is installed."""
+    reads = itertools.count()
+    tracer = obs_trace.Tracer(clock=lambda: float(next(reads)))
+    if traced:
+        obs_trace.install(tracer)
+    counter = obs_metrics.GLOBAL.counter("db_ingest_readbacks_total")
+    before = counter.value
+    db = BitmapDB(_schema(), backend="ref")
+    rng = np.random.default_rng(7)
+    enc = np.stack([rng.integers(0, 8, rows, dtype=np.int32),
+                    rng.integers(8, 16, rows, dtype=np.int32)], axis=1)
+    try:
+        assert db.append_encoded(enc) == rows
+    finally:
+        obs_trace.uninstall(tracer)
+    assert counter.value - before == (1 if rows else 0)
+    spans = tracer.spans()
+    if not (rows and traced):
+        assert spans == []
+        return
+    (root,) = [s for s in spans if s.name == "ingest.append"]
+    assert root.parent_id == 0
+    assert root.attrs == {"records": rows, "backend": "ref"}
+    kids = sorted((s for s in spans if s.parent_id == root.span_id),
+                  key=lambda s: s.t0)
+    assert tuple(s.name for s in kids) == INGEST_CHILDREN
+    assert len(spans) == 1 + len(kids)
+    assert all(s.trace_id == root.trace_id for s in kids)
+    assert root.t0 < kids[0].t0 and kids[-1].t1 < root.t1
+    for a, b in zip(kids, kids[1:]):
+        assert a.t0 < a.t1 < b.t0
 
 
 def test_disabled_path_records_nothing():

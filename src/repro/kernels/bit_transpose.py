@@ -1,7 +1,7 @@
 """Pallas TPU kernel: Transpose-Matrix (TM) stage of the BIC core.
 
 The ASIC's TM swaps buffer rows into BI columns with a wire permutation.
-With bits packed 32-per-uint32 (see cam_match.py) the TPU analogue is a
+With bits packed 32-per-uint32 (LSB-first, ref.py) the TPU analogue is a
 *bit-block* transpose: every aligned 32x32 bit tile is transposed in-register
 with a 5-round butterfly (Hacker's Delight 7-7), then tiles are permuted.
 No unpack to bytes ever happens, so VMEM/HBM traffic stays at 1 bit/bit.

@@ -51,26 +51,12 @@ def _tiled_block(total: int, block: int) -> tuple[int, int]:
 
 def cam_match(records: jax.Array, keys: jax.Array, *,
               interpret: bool | None = None) -> jax.Array:
-    """records (N, W) int, keys (M,) int -> packed (N, ceil(M/32)) uint32.
-
-    Pads N to a block multiple and M to 32; padded records use a sentinel
-    value no real key can match, padded keys match nothing by construction
-    (sentinel differs from the record pad sentinel).
-    """
-    if interpret is None:
-        interpret = interpret_mode()
-    N, W = records.shape
-    (M,) = keys.shape
-    # the output's lane axis is the key-word axis: whole, or 128-word blocks
-    block_mw, mw = _tiled_block(-(-M // PACK), 128)
-    block_m, Mp = block_mw * PACK, mw * PACK
-    block_n = _pick_block(_round_up(N, 8), 256, 8)
-    Np = _round_up(N, block_n)
-    rec = pad_records(records, Np)
-    ks = pad_keys(keys, Mp)
-    out = _cm.cam_match(rec, ks, block_n=block_n, block_m=block_m,
-                        interpret=interpret)
-    return out[:N]
+    """records (N, W) int, keys (M,) int -> packed (N, ceil(M/32)) uint32,
+    record-major: the key-major index of :func:`create_index` through
+    :func:`transpose` (padded keys read as keys no record holds)."""
+    n = records.shape[0]
+    key_major = create_index(records, keys, interpret=interpret)
+    return transpose(key_major, interpret=interpret)[:n]
 
 
 def transpose(packed: jax.Array, *, interpret: bool | None = None) -> jax.Array:
@@ -119,17 +105,45 @@ def query(rows: jax.Array, invert: jax.Array, *,
     return result[:Nw], count
 
 
+def _create_blocks(n: int, m: int) -> tuple[int, int, int, int]:
+    """(block_w, padded N, block_m, padded M) for :func:`create_index`.
+
+    The word axis (ceil(N/32)) is whole when it fits one vreg row of 128
+    lanes; past that it is rows of 128 words, whole up to 8 rows, else in
+    blocks of 8 rows.  Keys pad to ``KEYS_PER_PASS`` and block by up to
+    256, so the output block stays within 1 MiB."""
+    nw = -(-n // PACK)
+    if nw > _cm.LANES:
+        rows = -(-nw // _cm.LANES)
+        block_r, rows = _tiled_block(rows, _cm.SUBLANES)
+        nw, block_w = rows * _cm.LANES, block_r * _cm.LANES
+    else:
+        block_w = max(nw, 1)
+    mp = _round_up(max(m, 1), _cm.KEYS_PER_PASS)
+    block_m = _pick_block(mp, 256, _cm.KEYS_PER_PASS)
+    return block_w, max(nw, 1) * PACK, block_m, mp
+
+
 def create_index(records: jax.Array, keys: jax.Array, *,
                  interpret: bool | None = None) -> jax.Array:
-    """Full BIC pipeline (CAM match -> buffer -> TM transpose).
+    """Index creation: records (N, W), keys (M,) -> key-major packed
+    bitmap (M, ceil(N/32)) uint32.
 
-    records (N, W), keys (M,) -> key-major packed bitmap (M, ceil(N/32)).
-    Matches ``ref.create_index`` for 32-aligned shapes and is the kernel
-    realization of Fig. 3 of the paper.
+    One kernel (:func:`repro.kernels.cam_match.cam_match`) matches records
+    against keys and writes key-major words, the kernel realization of
+    Fig. 3 of the paper.  Records pad with the record sentinel and keys
+    with the key sentinel, so padding matches nothing; equals
+    ``ref.create_index`` on 32-aligned shapes.
     """
-    record_major = cam_match(records, keys, interpret=interpret)  # (N, Mw)
-    key_major = transpose(record_major, interpret=interpret)      # (Mw*32, ceil(N/32))
-    return key_major[: keys.shape[0]]
+    if interpret is None:
+        interpret = interpret_mode()
+    n = records.shape[0]
+    (m,) = keys.shape
+    block_w, np_, block_m, mp = _create_blocks(n, m)
+    out = _cm.cam_match(pad_records(records, np_), pad_keys(keys, mp),
+                        block_w=block_w, block_m=block_m,
+                        interpret=interpret)
+    return out[:m, :-(-n // PACK)]
 
 
 __all__ = ["cam_match", "transpose", "query", "create_index", "ref"]

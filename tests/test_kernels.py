@@ -16,18 +16,75 @@ RNG = np.random.default_rng(42)
 
 
 # ------------------------------------------------------------- cam_match
-@pytest.mark.parametrize("n,w,m,bn,bm", [
-    (8, 32, 32, 4, 32),          # paper-like core geometry
-    (16, 8, 64, 8, 32),
-    (64, 32, 128, 16, 64),
-    (256, 16, 256, 64, 128),
+def _want_index(records, keys):
+    """Key-major oracle for any shape: pad with the canonical sentinels,
+    run ``ref.create_index``, slice back."""
+    n, m = records.shape[0], keys.shape[0]
+    packed = ref.create_index(ref.pad_records(records), ref.pad_keys(keys))
+    return np.asarray(packed[:m, :ref.num_words(n)])
+
+
+def _sparse_keys(n, w, m):
+    """Keys neither sorted nor dense; records drawn from the keys and from
+    values that are no key."""
+    keys = RNG.choice(1 << 20, m, replace=False).astype(np.int32)
+    pool = np.concatenate([keys, RNG.integers(-5, 1 << 21, m)]).astype(np.int32)
+    return RNG.choice(pool, (n, w)), keys
+
+
+def _sentinels(n, w, m):
+    """Keys 0..M-1; records hold the record pad sentinel, the key pad
+    sentinel and words at or past M."""
+    keys = np.arange(m, dtype=np.int32)
+    records = RNG.integers(0, 2 * m, (n, w)).astype(np.int32)
+    records[RNG.random((n, w)) < 0.2] = ref.RECORD_SENTINEL
+    records[RNG.random((n, w)) < 0.1] = ref.KEY_SENTINEL
+    return records, keys
+
+
+@pytest.mark.parametrize("n,w,m,bw,bm", [
+    (32, 32, 32, 1, 32),          # paper-like core geometry, one word
+    (64, 8, 64, 2, 16),           # several key blocks
+    (4096, 32, 128, 128, 64),     # one whole vreg row of 128 words
+    (12288, 16, 256, 384, 128),   # whole axis of 3 rows (not a multiple of 8)
+    (65536, 4, 32, 1024, 8),      # two blocks of 8 x 128 words
+    (2048, 16, 48, 64, 24),       # key count not a multiple of 32
 ])
-def test_cam_match_kernel_shapes(n, w, m, bn, bm):
-    records = jnp.asarray(RNG.integers(0, 256, (n, w), dtype=np.int32))
-    keys = jnp.asarray(RNG.integers(0, 256, (m,), dtype=np.int32))
-    got = cam_match(records, keys, block_n=bn, block_m=bm, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(ref.cam_match(records, keys)))
+def test_cam_match_kernel_shapes(n, w, m, bw, bm):
+    records, keys = (jnp.asarray(a) for a in _sparse_keys(n, w, m))
+    got = cam_match(records, keys, block_w=bw, block_m=bm, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), _want_index(records, keys))
+
+
+@pytest.mark.parametrize("make,n,w,m", [
+    (_sparse_keys, 64, 32, 64),                                  # (a)
+    (_sparse_keys, 4100, 6, 40),                                 # (a)+(b)
+    (_sparse_keys, 77, 5, 9),                                    # (b)
+    (lambda n, w, m: (RNG.integers(0, m, (n, w)).astype(np.int32),
+                      RNG.permutation(m).astype(np.int32)),
+     300, 14, 1795),                                              # (c) SSB
+    (_sentinels, 200, 8, 64),                                    # (d)
+], ids=["unsorted-sparse-keys", "ragged-n-past-a-row",
+        "ragged-n", "ssb-widths", "sentinels-and-out-of-range"])
+def test_create_index_cases(make, n, w, m):
+    records, keys = (jnp.asarray(a) for a in make(n, w, m))
+    got = ops.create_index(records, keys)
+    np.testing.assert_array_equal(np.asarray(got), _want_index(records, keys))
+
+
+@pytest.mark.parametrize("raw", [False, True], ids=["ops", "kernel"])
+def test_create_index_batches_under_vmap(raw):
+    """(e) a leading block axis, as ``multicore_create_index`` maps it."""
+    blocks = jnp.asarray(RNG.integers(0, 40, (3, 96, 7), dtype=np.int32))
+    keys = jnp.asarray(RNG.permutation(40)[:32].astype(np.int32))
+    if raw:
+        fn = lambda r: cam_match(r, keys, block_w=3, block_m=16,
+                                 interpret=True)
+    else:
+        fn = lambda r: ops.create_index(r, keys)
+    got = np.asarray(jax.vmap(fn)(blocks))
+    for b in range(blocks.shape[0]):
+        np.testing.assert_array_equal(got[b], _want_index(blocks[b], keys))
 
 
 @pytest.mark.parametrize("dtype", [np.int32, np.uint8, np.int16])

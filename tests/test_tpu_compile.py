@@ -11,6 +11,7 @@ import — and every test skips where it cannot be described (no libtpu).
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +70,7 @@ def _kernels(hlo: str) -> int:
 
 def test_cam_match_compiles_for_v5e(one_chip):
     hlo = _compile(
-        functools.partial(cm.cam_match, block_n=256, block_m=M,
+        functools.partial(cm.cam_match, block_w=8 * 128, block_m=256,
                           interpret=False),
         _spec((BLOCK, W), jnp.int32, one_chip),
         _spec((M,), jnp.int32, one_chip))
@@ -105,11 +106,44 @@ def test_bulk_program_compiles_for_v5e(one_chip):
 
 
 def test_create_index_compiles_for_v5e(one_chip):
-    """The whole ingest pipeline (pad, CAM match, bit transpose, slice)."""
+    """The whole ingest pipeline (pad, relayout, match to key-major words,
+    slice): one kernel, no bit transpose."""
     hlo = _compile(functools.partial(ops.create_index, interpret=False),
                    _spec((BLOCK, W), jnp.int32, one_chip),
                    _spec((M,), jnp.int32, one_chip))
-    assert _kernels(hlo) == 2
+    assert _kernels(hlo) == 1
+
+
+def test_create_index_compiles_for_v5e_wide_records(one_chip):
+    """Records of 128 words: the records block outgrows the compiler's
+    default scoped VMEM, so the kernel asks for what its blocks need."""
+    hlo = _compile(functools.partial(ops.create_index, interpret=False),
+                   _spec((BLOCK // 4, 128), jnp.int32, one_chip),
+                   _spec((256,), jnp.int32, one_chip))
+    assert _kernels(hlo) == 1
+
+
+def test_create_index_runs_in_jit_cam_match(one_chip):
+    """Index creation dispatches one jit named ``cam_match`` that holds the
+    record relayout and the kernel, and it lowers to the module
+    ``jit_cam_match`` — the module the benchmark's ``create_roofline``
+    times."""
+    args = (_spec((BLOCK, W), jnp.int32, one_chip),
+            _spec((M,), jnp.int32, one_chip))
+    eqns = jax.make_jaxpr(functools.partial(ops.create_index,
+                                            interpret=False))(*args).eqns
+    jits = [e for e in eqns if "jaxpr" in e.params]
+    kernels = [e for e in jits
+               if any(q.primitive.name == "pallas_call"
+                      for q in e.params["jaxpr"].jaxpr.eqns)]
+    assert [e.params["name"] for e in kernels] == ["cam_match"]
+    inner = {q.primitive.name for q in kernels[0].params["jaxpr"].jaxpr.eqns}
+    assert "transpose" in inner
+    block_w, _, block_m, _ = ops._create_blocks(BLOCK, M)
+    lowered = cm.cam_match.lower(*args, block_w=block_w, block_m=block_m,
+                                 interpret=False)
+    assert re.search(r"^module @jit_cam_match\b", lowered.as_text(),
+                     re.MULTILINE)
 
 
 def test_pallas_bucket_executor_compiles_for_v5e(one_chip):

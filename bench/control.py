@@ -2,13 +2,16 @@
 broken, put in the program's place, compared as a run compares.
 
     python bench/control.py --workload <cell> --seeds 1,2,3 --seconds <s>
+        [--sessions <n>]
 
 ``ssb-sf1``'s control answers ranges as binned supersets (exact predicates
 broken); ``bic-paper``'s indexes all but the last word of each record.  For
 each seed it prints the numbers compared and their limits, which must come
 out not correct.  The program is not run: the control answers every query
-a window of ``--seconds`` would compare (``open_loop``), or every sampled
-block of ``--sessions`` whole sessions (``load``).
+a window of ``--seconds`` would compare (``open_loop``), each stream's
+first ``control_per_stream`` queries, a key of the mix (``closed_loop``:
+what a window on the chip got through), or every sampled block of
+``--sessions`` whole sessions (``load``).
 """
 import argparse
 import json
@@ -28,7 +31,9 @@ def control(cell_name: str, seed: int, seconds: float, sessions: int,
                                     root=root or harness.ROOT, sizes=sizes,
                                     mix_overrides=mix_overrides)
     drv.make_data()
-    if isinstance(drv, traffic.OpenLoop):
+    if isinstance(drv, traffic.ClosedLoop):
+        drv.plan_control()
+    elif isinstance(drv, traffic.OpenLoop):
         drv.plan_window(seconds)
     else:
         drv.plan_control(sessions)

@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+chip, in the closed-loop serving cell."""
+LAYER = "device"
+UNIT = "%"
+MOVES = "qps"
+
+
+def read(ctx):
+    return None if ctx.trace is None else 100.0 * ctx.trace.idle_share

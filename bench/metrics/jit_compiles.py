@@ -3,7 +3,7 @@ jit shapes, counted by the harness's ``jax.monitoring`` listener).  Set-up
 is meant to leave none."""
 LAYER = "executors (engine/batch.py, engine/bulk.py)"
 UNIT = "programs"
-MOVES = "p99_ms"
+MOVES = "qps"
 
 
 def read(ctx):

@@ -7,7 +7,7 @@ inside the traced window: those bytes / 819 GB/s, over the device time of
 the executor programs those waves ran."""
 LAYER = "kernels (kernels/bitmap_ops.py, engine/bulk.py)"
 UNIT = "%"
-MOVES = "p99_ms"
+MOVES = "qps"
 
 #: the bucket executors' compiled programs: the per-pass body (``run``)
 #: and the bulk sweep (``run_program``)

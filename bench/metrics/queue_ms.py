@@ -2,7 +2,7 @@
 ``queue`` span, enqueue to wave pickup), in ms."""
 LAYER = "front end (serve/service.py)"
 UNIT = "ms"
-MOVES = "p99_ms"
+MOVES = "qps"
 
 
 def read(ctx):

@@ -4,7 +4,7 @@ axis padded to a power of two (``bucket.dispatch`` spans: ``shape`` and
 ``q``)."""
 LAYER = "planner / cost model (engine/planner.py, engine/costmodel.py)"
 UNIT = "rows"
-MOVES = "p99_ms"
+MOVES = "qps"
 
 
 def _pow2(x):

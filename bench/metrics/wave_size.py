@@ -2,7 +2,7 @@
 the ``coalesce`` spans)."""
 LAYER = "front end (serve/service.py)"
 UNIT = "queries"
-MOVES = "p99_ms"
+MOVES = "qps"
 
 
 def read(ctx):

@@ -21,9 +21,11 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 from bench import control, harness, run  # noqa: E402
 
-#: the open-loop SSB cell's entries, which BENCHMARK.json leaves out until
-#: its bounds are measured on the chip (PERF.md, Open questions)
-SSB_CELL = pathlib.Path(ROOT) / "bench" / "tests" / "ssb-sf1.q12-q13.json"
+#: the entries of the SSB cells that BENCHMARK.json leaves out until the
+#: chip can measure them (PERF.md, Open questions): the open loop on Q1.2
+#: and Q1.3, and the closed loop on all 13 templates
+WAITING = [pathlib.Path(ROOT) / "bench" / "tests" / f"{cell}.json"
+           for cell in ("ssb-sf1.q12-q13", "ssb-sf1.streams")]
 
 TINY = {
     "ssb-sf1.q12-q13": (
@@ -31,6 +33,10 @@ TINY = {
              part_rows=2000, block_records=2048),
         dict(rate_per_s=200, warm_max_q=4, warm_burst_s=0.2,
              sample_per_template=1)),
+    "ssb-sf1.streams": (
+        dict(lineorder_rows=5000, customer_rows=300, supplier_rows=40,
+             part_rows=2000, block_records=2048),
+        dict(streams=8, warm_max_q=8, warm_s=0.2, sample_per_template=1)),
     "bic-paper.load": (
         dict(block_records=2048, session_records=8192, pool_records=8192),
         dict(sample_blocks=2)),
@@ -46,18 +52,23 @@ def _no_persistent_cache(monkeypatch):
 
 
 def with_ssb_cell(bm: dict) -> dict:
-    """``bm`` with the open-loop SSB cell's entries added."""
-    extra = json.loads(SSB_CELL.read_text())
-    for key, entries in extra.items():
-        have = {e["name"] for e in bm[key]}
-        bm[key] += [e for e in entries if e["name"] not in have]
+    """``bm`` with the waiting SSB cells' entries added; an entry that
+    ``bm`` already has gains the waiting cells in its ``workloads``."""
+    for path in WAITING:
+        for key, entries in json.loads(path.read_text()).items():
+            have = {e["name"]: e for e in bm[key]}
+            for e in entries:
+                if e["name"] not in have:
+                    bm[key].append(e)
+                elif "workloads" in e:
+                    have[e["name"]]["workloads"] += e["workloads"]
     return bm
 
 
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
     """A checkout of the benchmark whose BENCHMARK.json also holds the
-    open-loop SSB cell."""
+    waiting SSB cells."""
     root = tmp_path_factory.mktemp("checkout")
     (root / "bench").symlink_to(pathlib.Path(ROOT) / "bench")
     (root / "BENCHMARK.json").write_text(
@@ -133,6 +144,7 @@ def _flip_index_bit(monkeypatch):
 
 @pytest.mark.parametrize("cell,fault", [
     ("ssb-sf1.q12-q13", _flip_served_answer),
+    ("ssb-sf1.streams", _flip_served_answer),
     ("bic-paper.load", _flip_index_bit)])
 def test_broken_timed_path_is_not_correct(cell, fault, monkeypatch,
                                           checkout):
@@ -255,3 +267,261 @@ def test_seeded_schedule_keeps_its_work():
     assert sa.templates != sb.templates
     again = a.schedule("window", 2.0)
     assert np.array_equal(again.due, sa.due) and again.queries == sa.queries
+
+
+@pytest.mark.parametrize("cell", ["ssb-sf1.streams"])
+def test_traced_streams_run_reads_its_span_metrics(cell, checkout,
+                                                   monkeypatch):
+    """``--trace 1`` on a closed-loop cell: the span and counter metrics
+    come from the run (the CPU has no device trace, so the profiler is
+    left out and the trace's metrics read nothing)."""
+    monkeypatch.setattr(harness.Run, "window_started", lambda self, t0: None)
+    sizes, mix = TINY[cell]
+    out = harness.run_cell(cell, 6, 0.5, True,
+                           t_process=time.perf_counter(), root=checkout,
+                           sizes=sizes, mix_overrides=mix)
+    assert out["correct"] is True, out
+    listed = harness.cell_metrics(harness.load_benchmark(checkout), cell,
+                                  "per_layer")
+    assert set(out["metrics"]) == {m["name"] for m in listed
+                                   if m["source"] != "device_trace"}
+    assert out["metrics"]["wave_size"]["value"] >= 1
+
+
+
+
+class _FakeService:
+    """Serves submitted queries in first-in first-out waves of up to
+    ``wave`` from a thread of its own.  With ``log`` it logs every submit
+    (with the query's expression) and every read of a count, in order;
+    without, it keeps nothing of a query once it is served."""
+
+    def __init__(self, wave: int, log: bool = True):
+        import collections
+        import threading
+
+        self.wave = wave
+        self.log: list | None = [] if log else None
+        self._queue = collections.deque()
+        self._lock = threading.Lock()
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._serve, daemon=True)
+        self._thread.start()
+
+    def submit(self, expr):
+        fut = _FakeFuture(self)
+        with self._lock:
+            if self.log is not None:
+                fut.expr = repr(expr)
+                self.log.append(("submit", fut))
+            self._queue.append(fut)
+        return fut
+
+    def _serve(self):
+        while not self._stop.wait(0.001):
+            with self._lock:
+                take = [self._queue.popleft()
+                        for _ in range(min(self.wave, len(self._queue)))]
+            for fut in take:
+                fut.ev.set()
+
+    def close(self):
+        self._stop.set()
+        self._thread.join(timeout=10)
+        assert not self._thread.is_alive()
+
+
+class _FakeFuture:
+    trace_id = None
+
+    def __init__(self, svc):
+        import threading
+
+        self.svc, self.ev = svc, threading.Event()
+
+    def wait(self, timeout=None):
+        return self.ev.wait(timeout)
+
+    def done(self):
+        return self.ev.is_set()
+
+    def exception(self, timeout=None):
+        return None
+
+    @property
+    def count(self):
+        assert self.done()
+        if self.svc.log is not None:
+            with self.svc._lock:
+                self.svc.log.append(("read", self))
+        return 7
+
+    @property
+    def rows(self):
+        return np.zeros(2, np.uint32)
+
+
+def _generator(checkout, seed, **mix):
+    from bench import traffic
+
+    sizes, tiny = TINY["ssb-sf1.streams"]
+    _, gen = harness.make_generator("ssb-sf1.streams", seed, root=checkout,
+                                    sizes=sizes,
+                                    mix_overrides=dict(tiny, streams=5,
+                                                       **mix))
+    assert isinstance(gen, traffic.ClosedLoop)
+    gen.templates = list(gen.mix["templates"])
+    # a run's set-up has built expressions before its window: the first
+    # takes seconds of imports
+    traffic.to_expr(next(gen.stream(gen.WARM, 0))[1])
+    return gen
+
+
+def _drive(checkout, seed, wave, seconds=0.3, log=True):
+    """A closed-loop window against :class:`_FakeService`: the generator,
+    the window's record in submission order (each query drawn again from
+    its stream's seed, the sampled rows by position), and the service's
+    log."""
+    gen = _generator(checkout, seed)
+    gen.svc = svc = _FakeService(wave, log)
+    try:
+        w = gen.drive_streams(gen.WINDOW, seconds,
+                              keep=gen.mix["sample_per_template"])
+    finally:
+        svc.close()
+    f = gen.flatten(w["logs"])
+    j_of = {(int(s), int(k)): j
+            for j, (s, k) in enumerate(zip(f["stream"], f["k"]))}
+    f["template"], f["query"], f["priority"] = gen.replay(
+        gen.WINDOW, f["stream"], f["k"])
+    f["rows"] = {j_of[key]: r for key, r in w["rows"].items()}
+    return gen, f, svc.log
+
+
+def test_closed_loop_keeps_one_query_per_stream(checkout):
+    """Each stream has at most one query outstanding and submits its next
+    only after it has read the last one's count; each runs the templates
+    in its own seeded order; a seed gives every stream the same queries
+    whatever the service's pace."""
+    gen, w, log = _drive(checkout, 11, wave=3)
+    n = gen.mix["streams"]
+    subs = [f for kind, f in log if kind == "submit"]
+    assert len(subs) == len(w["query"]) > 3 * n
+    when = {(kind, id(f)): i for i, (kind, f) in enumerate(log)}
+    assert not w["failed"].any() and (w["count"] == 7).all()
+    per_stream: dict = {}
+    for j, s in enumerate(w["stream"]):
+        per_stream.setdefault(int(s), []).append(j)
+    assert sorted(per_stream) == list(range(n))
+    for s, js in per_stream.items():
+        # submit(j) < read(j) < submit(next j) for the stream's queries
+        for a, b in zip(js, js[1:]):
+            assert (when["submit", id(subs[a])] < when["read", id(subs[a])]
+                    < when["submit", id(subs[b])])
+        assert [w["k"][j] for j in js] == list(range(len(js)))
+        _, order = gen.order(gen.WINDOW, s)
+        assert sorted(order) == sorted(gen.templates)
+        assert [w["template"][j] for j in js] == [
+            order[k % len(order)] for k in range(len(js))]
+    orders = {tuple(gen.order(gen.WINDOW, s)[1]) for s in range(n)}
+    assert len(orders) > 1                  # each stream its own order
+
+    # the sampled rows: each template's lowest priorities among the
+    # queries read, as many as the mix asks for
+    k = gen.mix["sample_per_template"]
+    for t in set(w["template"]):
+        js = sorted((w["priority"][j], j) for j, tt in enumerate(w["template"])
+                    if tt == t)
+        assert {j for j in w["rows"] if w["template"][j] == t} == {
+            j for _, j in js[:k]}
+
+    def queries(w):
+        out: dict = {}
+        for s, q in zip(w["stream"], w["query"]):
+            out.setdefault(int(s), []).append(q)
+        return out
+
+    again = queries(_drive(checkout, 11, wave=1, seconds=0.2)[1])
+    first = queries(w)
+    for s, qs in again.items():
+        m = min(len(qs), len(first[s]))
+        assert m > 0 and qs[:m] == first[s][:m]
+    other = queries(_drive(checkout, 12, wave=3, seconds=0.2)[1])
+    assert any(other[s][:2] != first[s][:2] for s in other)
+
+
+def test_closed_loop_replays_what_the_clients_sent(checkout):
+    """The queries the comparison draws again from the streams' seeds are
+    the ones the clients submitted, in the order the service saw them."""
+    from bench import traffic
+
+    _, w, log = _drive(checkout, 13, wave=4)
+    sent = [f.expr for kind, f in log if kind == "submit"]
+    assert len(sent) == len(w["query"]) > 0
+    assert sent == [repr(traffic.to_expr(q)) for q in w["query"]]
+
+
+def test_closed_loop_keeps_no_object_per_query(checkout):
+    """The clients record a window in arrays: the objects the collector
+    tracks after the window do not grow with the queries it served."""
+    import gc
+
+    gen = _generator(checkout, 14)
+    gen.svc = svc = _FakeService(wave=5, log=False)
+    try:
+        gen.drive_streams(gen.WINDOW, 0.05, keep=0)     # threads, imports
+        gc.collect()
+        before = len(gc.get_objects())
+        w = gen.drive_streams(gen.WINDOW, 0.5, keep=0)
+        gc.collect()
+        after = len(gc.get_objects())
+    finally:
+        svc.close()
+    served = sum(g.n for g in w["logs"])
+    assert served > 500
+    assert after - before < 50 * gen.mix["streams"], (before, after, served)
+
+
+def test_stream_log_grows_without_losing_records():
+    from bench import traffic
+
+    log = traffic.StreamLog(cap=2)
+    for i in range(9):
+        k = log.add(float(i))
+        log.count[k] = 10 * i
+        log.failed[k] = i % 3 == 0
+    assert log.n == 9 and len(log.t_sub) == 16
+    assert list(log.t_sub[:9]) == [float(i) for i in range(9)]
+    assert list(log.count[:9]) == [10 * i for i in range(9)]
+    assert list(log.failed[:9]) == [i % 3 == 0 for i in range(9)]
+    assert np.isnan(log.t_read[:9]).all() and (log.count[9:] == -1).all()
+    assert log.failed[9:].all()
+
+
+def test_flatten_orders_the_streams_by_submit():
+    from bench import traffic
+
+    logs = [traffic.StreamLog(), traffic.StreamLog()]
+    for s, t in [(0, 0.5), (1, 0.1), (1, 0.7), (0, 0.9), (1, 1.0)]:
+        k = logs[s].add(t)
+        logs[s].count[k] = 100 * s + k
+    f = traffic.ClosedLoop.flatten(logs)
+    assert list(f["t_sub"]) == [0.1, 0.5, 0.7, 0.9, 1.0]
+    assert list(f["stream"]) == [1, 0, 1, 0, 1]
+    assert list(f["k"]) == [0, 0, 1, 1, 2]
+    assert list(f["count"]) == [100, 0, 101, 1, 102]
+
+
+@pytest.mark.parametrize("per_stream", [2, 3])
+def test_control_size_comes_from_the_cell(per_stream, checkout):
+    """The control answers each stream's first ``control_per_stream``
+    queries of the cell's mix, sampled as a run samples."""
+    gen = _generator(checkout, 15, control_per_stream=per_stream)
+    gen.make_data()
+    gen.plan_control()
+    assert len(gen.window.queries) == per_stream * gen.mix["streams"]
+    first = [next(gen.stream(gen.WINDOW, s))[1]
+             for s in range(gen.mix["streams"])]
+    assert first == gen.window.queries[::per_stream]
+    k = gen.mix["sample_per_template"]
+    assert len(gen.sample) == k * len(set(gen.window.templates))

@@ -19,8 +19,8 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 TRACE = os.path.join(ROOT, "bench", "testdata", "create_index.xplane.pb")
 
 BM = harness.load_benchmark()
-#: BENCHMARK.json and the open-loop SSB cell, which it leaves out until
-#: its bounds are measured (``bench/tests/ssb-sf1.q12-q13.json``)
+#: BENCHMARK.json and the SSB cells it leaves out until the chip can
+#: measure them (``bench/tests/ssb-sf1.q12-q13.json``, ``.streams.json``)
 ALL = with_ssb_cell(harness.load_benchmark())
 
 

@@ -14,16 +14,30 @@ stamps each as it resolves; one reader thread then reads each answer's
 window).  Latency runs from the due time to the stamp, so a stall counts
 against every query it delays, and no read delays a later stamp.
 
+``closed_loop``: ``streams`` clients, each with exactly one query
+outstanding against ``BitmapDB.serve()``.  Each runs the templates in its
+own seeded order, repeated, with fresh parameters per query, and submits
+its next query as soon as it has read the ``.count`` of the last one: no
+think time.  Each stream is a client thread of its own, so no client's
+read waits for another's.  A client records its times and counts in
+arrays and keeps no Python object per query; the window's queries are
+drawn again from the streams' seeds for the comparison.  The end-to-end
+metric is the queries whose count was read inside the window, per
+second.
+
 ``load``: record blocks appended back to back through
 ``BitmapDB.append_encoded`` from a host pool, a fresh session every
 ``session_records``.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import functools
 import gc
+import heapq
+import itertools
 import queue
 import threading
 import time
@@ -47,6 +61,15 @@ class Check:
     @property
     def ok(self) -> bool:
         return self.value <= self.limit
+
+
+def sub_rng(seed: int, stream: str, *sub: int) -> np.random.Generator:
+    """One of many independent generators of ``stream`` for ``seed``
+    (``sub``: non-negative integers, such as a client's number); none is
+    any generator of :func:`reference.rng`, whose keys are shorter."""
+    return np.random.default_rng([reference.STREAMS[stream],
+                                  seed % (1 << 64), (seed >> 64) % (1 << 64),
+                                  *sub])
 
 
 def pow2_upto(cap: int) -> list:
@@ -79,7 +102,7 @@ class Traffic:
         self.phases[name] = time.perf_counter() - t0
 
 
-# ------------------------------------------------------------ open loop
+# ------------------------------------------------------- filter queries
 def to_expr(query):
     """A reference query (tuple of predicates) as the program's DSL
     expression, in the form the SQL states it."""
@@ -123,15 +146,14 @@ class Served:
     t_close: float
 
 
-class OpenLoop(Traffic):
-    """Open-loop filter queries at ``rate_per_s`` (see module docstring).
-
-    Mix keys: ``rate_per_s``, ``templates`` (null: all of the
-    configuration's), ``warm_max_q`` (widest one-template bucket warmed),
-    ``warm_burst_s`` (untimed traffic before the window),
-    ``sample_per_template`` (row sets compared per template),
-    ``profile_lead_s``/``profile_s`` (the traced part of a ``--trace 1``
-    window)."""
+class FilterTraffic(Traffic):
+    """Filter queries against ``BitmapDB.serve()``: what the open and the
+    closed loop share.  The data, the service and its warm-up, the
+    reference, the control's answers, the comparison and the bytes a query
+    must move are here; a subclass runs the window and leaves ``window``
+    (the :class:`Schedule` of the queries compared), ``sample`` (those
+    whose row sets are compared) and ``served`` (the :class:`Served`
+    answers)."""
 
     def make_data(self) -> None:
         """The generated rows and the column domains (set-up phase
@@ -143,7 +165,10 @@ class OpenLoop(Traffic):
                           or list(self.ref.TEMPLATES))
         self.nw = -(-len(next(iter(self.rows.values()))) // 32)
 
-    def setup(self) -> None:
+    def start_service(self) -> None:
+        """Data, ingest, ``serve()``, and the warm-up of every
+        one-template bucket and every read the traffic reaches (set-up
+        phases ``data``, ``ingest``, ``warmup``, ``warm_reads``)."""
         import jax
 
         import repro
@@ -194,6 +219,81 @@ class OpenLoop(Traffic):
                     counts[qi].block_until_ready()
                     rows[qi].block_until_ready()
             del rows, counts
+
+    def release(self) -> None:
+        h = self.svc.health()
+        self.notes.update({k: h[k] for k in (
+            "fallback_queries", "degraded_waves", "wave_retries",
+            "isolated_failures", "deadline_rejected")})
+        m = self.svc.metrics()
+        self.notes.update(waves=m.batches, wave_mean=m.batch_mean,
+                          wave_max=m.batch_max)
+        self.svc.close()
+        del self.svc, self.db
+        gc.collect()
+
+    @property
+    def attempted(self) -> int:
+        return len(self.window.queries)
+
+    @property
+    def failed(self) -> int:
+        return int(self.served.failed.sum())
+
+    def answers(self, control: bool = False):
+        """(counts, sampled rows) of the window: the program's, or with
+        ``control`` the control's (the reference with ranges answered as
+        binned supersets) put in the program's place."""
+        if not control:
+            return self.served.counts, self.served.rows
+        fr = self._reference()
+        w = self.ref.CONTROL_BIN
+        counts = np.array([fr.count(q, w) for q in self.window.queries])
+        return counts, {i: fr.row(self.window.queries[i], w)
+                        for i in self.sample}
+
+    def _reference(self):
+        fr = getattr(self, "_fr", None)
+        if fr is None:
+            fr = self._fr = reference.FilterReference(dict(self.domains),
+                                                      self.rows)
+        return fr
+
+    def check(self, control: bool = False) -> list:
+        fr = self._reference()
+        counts, rows = self.answers(control)
+        qs = self.window.queries
+        want = np.array([fr.count(q) for q in qs])
+        ok = (np.ones(len(qs), bool) if control
+              else ~self.served.failed)
+        bad_rows = sum(1 for i, row in rows.items()
+                       if not np.array_equal(row, fr.row(qs[i])))
+        return [Check("unanswered", float(len(qs) - ok.sum()), 0),
+                Check("count_mismatches",
+                      float(np.count_nonzero((counts != want) & ok)), 0),
+                Check("row_mismatches", float(bad_rows), 0)]
+
+    def query_bytes(self, idx) -> float:
+        """Bytes the queries ``idx`` must move at least: each distinct key
+        row they reference, plus one output row, once."""
+        fr = self._reference()
+        qs = self.window.queries
+        return float(sum(fr.key_rows(qs[i]) + 1 for i in idx)
+                     * self.nw * 4)
+
+
+class OpenLoop(FilterTraffic):
+    """Open-loop filter queries at ``rate_per_s`` (see module docstring).
+
+    Mix keys: ``rate_per_s``, ``templates`` (null: all of the
+    configuration's), ``warm_max_q`` (widest one-template bucket warmed),
+    ``warm_burst_s`` (untimed traffic before the window),
+    ``sample_per_template`` (row sets compared per template),
+    ``profile_lead_s``/``profile_s`` (the traced part of a ``--trace 1``
+    window)."""
+
+    def setup(self) -> None:
+        self.start_service()
         with self.phase("burst"):
             burst = self.schedule("burst", self.mix["warm_burst_s"])
             self.drive(burst, sample=set(range(0, len(burst.queries), 7)))
@@ -348,26 +448,6 @@ class OpenLoop(Traffic):
                        r.choice(idx, min(k, len(idx)), replace=False))
         return out
 
-    def release(self) -> None:
-        h = self.svc.health()
-        self.notes.update({k: h[k] for k in (
-            "fallback_queries", "degraded_waves", "wave_retries",
-            "isolated_failures", "deadline_rejected")})
-        m = self.svc.metrics()
-        self.notes.update(waves=m.batches, wave_mean=m.batch_mean,
-                          wave_max=m.batch_max)
-        self.svc.close()
-        del self.svc, self.db
-        gc.collect()
-
-    @property
-    def attempted(self) -> int:
-        return len(self.window.queries)
-
-    @property
-    def failed(self) -> int:
-        return int(self.served.failed.sum())
-
     def end_to_end(self) -> dict:
         lat = self.latency_ms
         lag = self.lag_ms[~np.isnan(self.lag_ms)]
@@ -380,46 +460,264 @@ class OpenLoop(Traffic):
             offered_per_s=len(lat) / (self.t1 - self.t0))
         return {"p99_ms": float(np.percentile(lat, 99))}
 
-    def answers(self, control: bool = False):
-        """(counts, sampled rows) of the window: the program's, or with
-        ``control`` the control's (the reference with ranges answered as
-        binned supersets) put in the program's place."""
-        if not control:
-            return self.served.counts, self.served.rows
-        fr = self._reference()
-        w = self.ref.CONTROL_BIN
-        counts = np.array([fr.count(q, w) for q in self.window.queries])
-        return counts, {i: fr.row(self.window.queries[i], w)
-                        for i in self.sample}
 
-    def _reference(self):
-        fr = getattr(self, "_fr", None)
-        if fr is None:
-            fr = self._fr = reference.FilterReference(dict(self.domains),
-                                                      self.rows)
-        return fr
+# ----------------------------------------------------------- closed loop
+class StreamLog:
+    """One client's record of its queries, in arrays that double when
+    full: when it submitted each, saw it resolve and read its count, the
+    count, and whether it failed.  The client keeps no Python object per
+    query; the queries are drawn again from the stream's seed once the
+    window is over (:meth:`ClosedLoop.replay`)."""
 
-    def check(self, control: bool = False) -> list:
-        fr = self._reference()
-        counts, rows = self.answers(control)
-        qs = self.window.queries
-        want = np.array([fr.count(q) for q in qs])
-        ok = (np.ones(len(qs), bool) if control
-              else ~self.served.failed)
-        bad_rows = sum(1 for i, row in rows.items()
-                       if not np.array_equal(row, fr.row(qs[i])))
-        return [Check("unanswered", float(len(qs) - ok.sum()), 0),
-                Check("count_mismatches",
-                      float(np.count_nonzero((counts != want) & ok)), 0),
-                Check("row_mismatches", float(bad_rows), 0)]
+    #: each field's type and the value of a query not yet answered
+    FIELDS = {"t_sub": (float, np.nan), "t_seen": (float, np.nan),
+              "t_read": (float, np.nan), "count": (np.int64, -1),
+              "failed": (bool, True)}
 
-    def query_bytes(self, idx) -> float:
-        """Bytes the queries ``idx`` must move at least: each distinct key
-        row they reference, plus one output row, once."""
-        fr = self._reference()
-        qs = self.window.queries
-        return float(sum(fr.key_rows(qs[i]) + 1 for i in idx)
-                     * self.nw * 4)
+    def __init__(self, cap: int = 64):
+        self.n = 0
+        for name, (dtype, fill) in self.FIELDS.items():
+            setattr(self, name, np.full(cap, fill, dtype))
+
+    def add(self, t_sub: float) -> int:
+        """Records a submit; returns the query's number in the stream."""
+        k = self.n
+        if k == len(self.t_sub):
+            for name, (dtype, fill) in self.FIELDS.items():
+                setattr(self, name, np.concatenate(
+                    [getattr(self, name), np.full(k, fill, dtype)]))
+        self.t_sub[k] = t_sub
+        self.n = k + 1
+        return k
+
+
+class ClosedLoop(FilterTraffic):
+    """``streams`` clients in a closed loop against ``BitmapDB.serve()``
+    (see module docstring).
+
+    Mix keys: ``streams``, ``templates`` (null: all of the
+    configuration's), ``warm_max_q`` (widest one-template bucket warmed),
+    ``warm_s`` (untimed streams before the window),
+    ``sample_per_template`` (row sets compared per template),
+    ``control_per_stream`` (queries of each stream the control answers:
+    what one stream got through in a window on the chip),
+    ``profile_lead_s``/``profile_s`` (the traced part of a ``--trace 1``
+    window)."""
+
+    #: ``phase`` of :meth:`stream`: the window's streams and the warm-up's
+    WINDOW, WARM = 0, 1
+
+    def setup(self) -> None:
+        self.start_service()
+        with self.phase("warm_streams"):
+            self.drive_streams(self.WARM, float(self.mix["warm_s"]), keep=0)
+
+    def order(self, phase: int, s: int) -> tuple:
+        """Stream ``s``'s generator, and its seeded order of the templates
+        (the generator's first draw)."""
+        r = sub_rng(self.seed, "queries", phase, s)
+        return r, [self.templates[i]
+                   for i in r.permutation(len(self.templates))]
+
+    def stream(self, phase: int, s: int):
+        """Stream ``s``'s queries, endlessly: ``(template, query,
+        priority)``, its :meth:`order` of the templates repeated, each
+        query with parameters drawn afresh from the stream's own generator
+        (the same seed gives every stream the same sequence, however the
+        run interleaves them).  ``priority``, uniform in [0, 1) from a
+        generator of its own, picks the sampled row sets: the
+        ``sample_per_template`` lowest of each template."""
+        r, order = self.order(phase, s)
+        pr = sub_rng(self.seed, "sample", phase, s)
+        for k in itertools.count():
+            t = order[k % len(order)]
+            yield t, self.ref.draw(self.cfg, r, t), float(pr.random())
+
+    def drive_streams(self, phase: int, seconds: float, keep: int,
+                      on_start=None) -> dict:
+        """Run the streams for ``seconds``, one client thread each: a
+        client submits its query, waits for it, reads its ``.count``, and
+        submits its next, until the window closes.  Its query still
+        outstanding then is waited for (up to :data:`LATE_S`), read and
+        compared, but not counted as done in the window.  The rows of the
+        ``keep`` lowest-priority queries of each template read so far are
+        held (a seeded uniform sample of what the window answered).
+
+        Returns each stream's :class:`StreamLog`, the sampled rows and the
+        trace ids (keyed by ``(stream, k)``), and the window's times."""
+        import jax
+
+        svc = self.svc
+        n = int(self.mix["streams"])
+        lock = threading.Lock()
+        kept: dict = collections.defaultdict(list)  # template -> heap
+        rows: dict = {}                # (stream, k) -> rows on the device
+        trace_ids: dict = {}           # trace id -> (stream, k); traced only
+        logs = [StreamLog() for _ in range(n)]
+        errors: list = []
+        go = threading.Event()
+        t_start = t_end = give_up = 0.0
+
+        def sample(key, t, pri, fut) -> None:
+            with lock:
+                heap = kept[t]
+                if len(heap) < keep or (heap and pri < -heap[0][0]):
+                    rows[key] = fut.rows
+                    heapq.heappush(heap, (-pri, key))
+                    if len(heap) > keep:
+                        del rows[heapq.heappop(heap)[1]]
+
+        def client(s: int) -> None:
+            try:
+                log = logs[s]
+                it = self.stream(phase, s)
+                go.wait()
+                while time.perf_counter() < t_end:
+                    t, q, pri = next(it)
+                    k = log.add(time.perf_counter())
+                    try:
+                        # built as a client builds it, when it is sent
+                        fut = svc.submit(to_expr(q))
+                    except Exception:   # noqa: BLE001 — counted
+                        continue
+                    if not fut.wait(max(0.0, give_up - time.perf_counter())):
+                        return
+                    log.t_seen[k] = time.perf_counter()
+                    if fut.exception(0) is not None:
+                        continue
+                    log.count[k] = fut.count      # the client's read
+                    log.t_read[k] = time.perf_counter()
+                    log.failed[k] = False
+                    if fut.trace_id is not None:
+                        trace_ids[fut.trace_id] = (s, k)
+                    if keep:
+                        sample((s, k), t, pri, fut)
+            except BaseException as e:          # noqa: BLE001 — re-raised
+                errors.append(e)
+
+        threads = [threading.Thread(target=client, args=(s,),
+                                    name=f"bench-stream-{s}")
+                   for s in range(n)]
+        for th in threads:
+            th.start()
+        t_start = time.perf_counter()
+        t_end = t_start + seconds
+        give_up = t_end + LATE_S
+        go.set()
+        if on_start is not None:
+            on_start(t_start)
+        for th in threads:
+            th.join(timeout=max(1.0, give_up - time.perf_counter()) + 30)
+            if th.is_alive():
+                raise RuntimeError(f"{th.name} did not finish")
+        if errors:
+            raise errors[0]
+        return dict(logs=logs, trace_ids=trace_ids,
+                    rows={key: np.asarray(jax.device_get(r))
+                          for key, r in rows.items()},
+                    t_start=t_start, t_end=t_end,
+                    t_close=time.perf_counter())
+
+    @staticmethod
+    def flatten(logs: list) -> dict:
+        """The streams' records as one record in submission order:
+        ``stream`` and ``k`` (its number in the stream) of each query, and
+        each :class:`StreamLog` field."""
+        stream = np.concatenate([np.full(g.n, s) for s, g in
+                                 enumerate(logs)]).astype(np.int64)
+        k = np.concatenate([np.arange(g.n) for g in logs]).astype(np.int64)
+        cols = {name: np.concatenate([getattr(g, name)[:g.n]
+                                      for g in logs])
+                for name in StreamLog.FIELDS}
+        by_sub = np.argsort(cols["t_sub"], kind="stable")
+        return {"stream": stream[by_sub], "k": k[by_sub],
+                **{name: a[by_sub] for name, a in cols.items()}}
+
+    def replay(self, phase: int, stream, k) -> tuple:
+        """``(templates, queries, priorities)`` of the queries ``(stream,
+        k)``, drawn again from each stream's seed as :meth:`stream` drew
+        them for the clients."""
+        drawn = {int(s): list(itertools.islice(self.stream(phase, int(s)),
+                                               int(n)))
+                 for s, n in zip(*np.unique(stream, return_counts=True))}
+        got = [drawn[int(s)][int(j)] for s, j in zip(stream, k)]
+        return ([t for t, _, _ in got], [q for _, q, _ in got],
+                [p for _, _, p in got])
+
+    def plan_control(self) -> None:
+        """The window the control answers, without the program: each
+        stream's first ``control_per_stream`` queries, sampled as a run
+        samples."""
+        n = int(self.mix["control_per_stream"])
+        streams = int(self.mix["streams"])
+        stream = np.repeat(np.arange(streams), n)
+        tmpl, qs, pri = self.replay(self.WINDOW, stream,
+                                    np.tile(np.arange(n), streams))
+        k = int(self.mix["sample_per_template"])
+        by_t: dict = collections.defaultdict(list)
+        for j, (t, p) in enumerate(zip(tmpl, pri)):
+            by_t[t].append((p, j))
+        self.sample = {j for cand in by_t.values()
+                       for _, j in sorted(cand)[:k]}
+        self.window = Schedule(np.zeros(len(qs)), qs, tmpl, np.zeros(0))
+
+    def measure(self, seconds: float) -> None:
+        from repro.obs import metrics
+
+        def engine():
+            # waves served and bucket executors called, program-wide
+            c = dict(metrics.GLOBAL.collect())
+            return (c["engine_waves_total"].value,
+                    c["engine_bucket_dispatches_total"].value)
+
+        e0 = engine()
+        w = self.drive_streams(self.WINDOW, seconds,
+                               keep=int(self.mix["sample_per_template"]),
+                               on_start=self.run.window_started)
+        waves, dispatches = (b - a for a, b in zip(e0, engine()))
+        self.notes["dispatches_per_wave"] = dispatches / max(waves, 1)
+        self.record = f = self.flatten(w["logs"])
+        j_of = {(int(s), int(k)): j
+                for j, (s, k) in enumerate(zip(f["stream"], f["k"]))}
+        rows = {j_of[key]: r for key, r in w["rows"].items()}
+        self.sample = set(rows)
+        self.served = Served(w["t_start"], f["t_sub"], f["t_seen"],
+                             f["count"], f["failed"], rows,
+                             {tid: j_of[key]
+                              for tid, key in w["trace_ids"].items()},
+                             w["t_close"])
+        self.t0, self.t1 = w["t_start"], w["t_end"]
+        self.notes["rows_compared"] = len(self.sample)
+        self.done_in_window = int(np.count_nonzero(
+            ~f["failed"] & (f["t_read"] <= w["t_end"])))
+        self.latency_ms = (f["t_read"] - f["t_sub"]) * 1e3
+        # from a query's resolution to its stream's next submit
+        self.resubmit_ms = np.concatenate([
+            (g.t_sub[1:g.n] - g.t_seen[:g.n - 1]) * 1e3
+            for g in w["logs"]])
+
+    @functools.cached_property
+    def window(self) -> Schedule:
+        """The queries the window submitted, in submission order, drawn
+        again from their streams' seeds (set by :meth:`plan_control` for
+        the control)."""
+        f = self.record
+        tmpl, qs, _ = self.replay(self.WINDOW, f["stream"], f["k"])
+        return Schedule(f["t_sub"] - self.t0, qs, tmpl, np.zeros(0))
+
+    def end_to_end(self) -> dict:
+        lat = self.latency_ms[~np.isnan(self.latency_ms)]
+        lag = self.resubmit_ms[~np.isnan(self.resubmit_ms)]
+        self.notes.update(
+            queries=len(self.record["t_sub"]),
+            done_in_window=self.done_in_window,
+            rtt_p50_ms=float(np.percentile(lat, 50)) if lat.size else None,
+            rtt_p99_ms=float(np.percentile(lat, 99)) if lat.size else None,
+            resubmit_p50_ms=float(np.percentile(lag, 50)) if lag.size
+            else None,
+            resubmit_p99_ms=float(np.percentile(lag, 99)) if lag.size
+            else None)
+        return {"qps": self.done_in_window / (self.t1 - self.t0)}
 
 
 # ------------------------------------------------------------------ load
@@ -569,7 +867,8 @@ class Load(Traffic):
         return float(records * w * 4 + self.cfg["num_keys"] * records / 8)
 
 
-GENERATORS = {"open_loop": OpenLoop, "load": Load}
+GENERATORS = {"open_loop": OpenLoop, "closed_loop": ClosedLoop,
+              "load": Load}
 
 
 def generator_for(mix: dict):

@@ -33,7 +33,9 @@ stacked into one vmapped dispatch when word counts are uniform).
 """
 from __future__ import annotations
 
+import collections
 import os
+import threading
 import warnings
 from typing import Sequence
 
@@ -54,7 +56,18 @@ SCHEMA_FILE = "SCHEMA.json"
 
 _INGEST_READBACKS = obs_metrics.GLOBAL.counter(
     "db_ingest_readbacks_total",
-    "blocking device-to-host reads made by append_encoded")
+    "blocking device-to-host reads of the per-key counts that "
+    "append_encoded keeps on the device")
+
+#: blocks ``append_encoded`` leaves unfinished on the device.  Two let
+#: block k+1's host-to-device copy run under block k's build, while the
+#: host cannot queue more record blocks, nor more splice outputs (each
+#: the size of the session's buffer), in HBM than that.
+_INFLIGHT_BLOCKS = 2
+
+#: the device count accumulator is int32: it is folded into the host's
+#: int64 counts before the records it covers could pass int32's range
+_COUNT_FOLD_RECORDS = 2**31 - 1
 
 
 def include_exclude_pred(include: Sequence[int] = (),
@@ -78,6 +91,13 @@ def _popcounts(packed) -> np.ndarray:
     if arr.size == 0:
         return np.zeros((arr.shape[0],), np.int64)
     return np.bitwise_count(arr).sum(axis=1, dtype=np.int64)
+
+
+@jax.jit
+def _add_popcounts(acc: jax.Array, block: jax.Array) -> jax.Array:
+    """``acc`` (M,) int32 plus the per-key set-bit counts of ``block``."""
+    return acc + jnp.sum(jax.lax.population_count(block), axis=1,
+                         dtype=jnp.int32)
 
 
 class BitmapDB:
@@ -111,7 +131,15 @@ class BitmapDB:
             # (one process serving a shard per chip)
             self._keys = jax.device_put(self._keys, device)
         self._index = None                     # read-only sessions only
+        # exact per-key counts: host int64 ``_counts`` plus the device
+        # int32 ``_acc`` of the blocks appended since the last fold;
+        # ``_counted`` records are covered by the two together
         self._counts = np.zeros((m,), np.int64)
+        self._acc = jnp.zeros_like(self._keys)
+        self._acc_records = 0
+        self._counted = 0
+        self._count_mu = threading.Lock()
+        self._inflight: collections.deque = collections.deque()
         self._plans: dict = {}
         self._plans_by_id: dict = {}       # id(expr) fast path (see _plan_for)
         # typed counters in a per-session registry; cache_stats() is a
@@ -137,6 +165,7 @@ class BitmapDB:
                 store, self._keys, backend=self._create_backend,
                 capacity_words=capacity_words, flush_records=spill_records)
             self._counts = _popcounts(self._si.index.packed)
+            self._counted = self._si.num_records
             return
         self._si = StreamingIndexer(self._keys,
                                     backend=self._create_backend,
@@ -231,7 +260,8 @@ class BitmapDB:
     @property
     def stats(self) -> planner.KeyStats:
         """Live per-key set-bit counts (exact) as planner cardinality
-        estimates."""
+        estimates.  A live session reads its device counts (one (M,)
+        int32 read) only when records were counted since the last call."""
         if self._counts is None:           # read-only: popcount on demand
             idx = self._index
             if hasattr(idx, "parts"):      # StoredIndex
@@ -241,11 +271,43 @@ class BitmapDB:
                 self._counts = c
             else:
                 self._counts = _popcounts(idx.packed)
-        n = self.num_records
-        if self._stats_cache is None or self._stats_cache[0] != n:
-            self._stats_cache = (n, planner.KeyStats(
-                tuple(int(c) for c in self._counts), n))
-        return self._stats_cache[1]
+            self._counted = self.num_records
+        with self._count_mu:
+            n = self._counted
+            if self._stats_cache is None or self._stats_cache[0] != n:
+                self._fold_counts()
+                self._stats_cache = (n, planner.KeyStats(
+                    tuple(int(c) for c in self._counts), n))
+            return self._stats_cache[1]
+
+    def _fold_counts(self) -> None:
+        """Add the device accumulator into the host counts and restart it
+        (caller holds ``_count_mu``)."""
+        if self._acc_records:
+            self._counts += np.asarray(jax.device_get(self._acc))
+            _INGEST_READBACKS.inc()
+            self._acc = jnp.zeros_like(self._acc)
+            self._acc_records = 0
+
+    def _wait_inflight(self) -> None:
+        """With ``_INFLIGHT_BLOCKS`` blocks unwaited, wait for the oldest:
+        its count was dispatched after its splice, and the device runs
+        programs in order."""
+        with self._count_mu:
+            if len(self._inflight) < _INFLIGHT_BLOCKS:
+                return
+            oldest = self._inflight.popleft()
+        oldest.block_until_ready()
+
+    def _count_block(self, block: jax.Array, n: int) -> None:
+        """Add ``block``'s per-key counts on the device."""
+        with self._count_mu:
+            if self._acc_records + n > _COUNT_FOLD_RECORDS:
+                self._fold_counts()
+            self._acc = _add_popcounts(self._acc, block)
+            self._acc_records += n
+            self._counted += n
+            self._inflight.append(self._acc)
 
     # --------------------------------------------------------------- ingest
     def ingest(self, rows) -> int:
@@ -265,11 +327,21 @@ class BitmapDB:
         """Stream pre-encoded key-word records (N, W): each int word is a
         global key id (words outside [0, num_keys) match no key).
 
+        Returns once the block's copy, index creation and splice are
+        dispatched, leaving at most ``_INFLIGHT_BLOCKS`` blocks unfinished
+        on the device: the next block's copy runs under this block's
+        build.  A view or query taken after the return includes the block
+        (the splice is ordered on the buffer), and a durable block is in
+        the WAL.  Per-key counts stay on the device until :attr:`stats`
+        reads them.
+
         Traced, a block is one ``ingest.append`` span whose children run
         in order: ``ingest.upload`` (host-to-device copy),
-        ``ingest.create`` (index creation dispatched), ``ingest.splice``
-        (WAL, grow, splice, spill), ``ingest.wait`` (the device finishes
-        the block) and ``ingest.readback`` (the block's popcount read)."""
+        ``ingest.create`` (index creation dispatched), ``ingest.wait``
+        (the device finishes the block ``_INFLIGHT_BLOCKS`` back, before
+        this splice allocates its output), ``ingest.splice`` (WAL, grow,
+        splice, spill) and ``ingest.count`` (the block's per-key counts
+        added on the device)."""
         if self._si is None:
             raise RuntimeError("read-only session (from_index) — open a "
                                "BitmapDB with a schema/path to ingest")
@@ -278,24 +350,28 @@ class BitmapDB:
             raise ValueError(f"records must be (N, W), got {shape}")
         if not shape[0]:
             return self.num_records
+        if not isinstance(records, jax.Array):
+            # host records: the WAL logs this array, no device round trip
+            records = np.asarray(records, np.int32)
         with maybe_span("ingest.append", records=shape[0],
                         backend=self._create_backend):
             with maybe_span("ingest.upload") as sp:
-                records = jnp.asarray(records, jnp.int32)
+                dev = jnp.asarray(records, jnp.int32)
                 if sp is not None:
                     # the copy returns before its DMA ends: traced, the
                     # span waits for it, so the copy is not timed as wait
-                    records.block_until_ready()
+                    dev.block_until_ready()
             with maybe_span("ingest.create"):
                 block = backends.get_backend(
-                    self._create_backend).create_index(records, self._keys)
-            with maybe_span("ingest.splice"):
-                self._si.append_indexed(records, block)
+                    self._create_backend).create_index(dev, self._keys)
             with maybe_span("ingest.wait"):
-                block.block_until_ready()
-            with maybe_span("ingest.readback"):
-                self._counts += _popcounts(block)
-                _INGEST_READBACKS.inc()
+                self._wait_inflight()
+            with maybe_span("ingest.splice"):
+                self._si.append_indexed(
+                    records if isinstance(records, np.ndarray) else dev,
+                    block)
+            with maybe_span("ingest.count"):
+                self._count_block(block, shape[0])
         return self.num_records
 
     # ----------------------------------------------------------- durability

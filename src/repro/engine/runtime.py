@@ -352,11 +352,12 @@ class StreamingIndexer:
             else:
                 self.spill()
 
-    def _log_block(self, records: jax.Array, start: int,
+    def _log_block(self, records, start: int,
                    tick: int | None = None) -> None:
         if self._store is not None:
-            self._store.log_block(np.asarray(jax.device_get(records)),
-                                  start, tick)
+            if not isinstance(records, np.ndarray):     # a device array
+                records = np.asarray(jax.device_get(records))
+            self._store.log_block(records, start, tick)
 
     @classmethod
     def restore(cls, store, keys, *, backend: str = "auto",
@@ -402,18 +403,21 @@ class StreamingIndexer:
             return self.index
         block = backends.get_backend(self.backend).create_index(
             records, self.keys)
-        return self.append_indexed(records, block)
+        self.append_indexed(records, block)
+        return self.index
 
-    def append_indexed(self, records: jax.Array, block: jax.Array, *,
-                       tick: int | None = None) -> policy.BitmapIndex:
+    def append_indexed(self, records, block: jax.Array, *,
+                       tick: int | None = None) -> None:
         """Splice in a block whose (M, ceil(N'/32)) index ``block`` was
         already built elsewhere (e.g. by a sharded tick dispatch) — the raw
-        ``records`` are still WAL-logged so recovery can re-index them.
-        ``tick`` stamps the block for replay idempotence (see
-        :attr:`last_tick`)."""
+        ``records`` (host or device) are still WAL-logged so recovery can
+        re-index them.  ``tick`` stamps the block for replay idempotence
+        (see :attr:`last_tick`).  Returns nothing: read :attr:`index` or
+        :meth:`view` for the result (each :attr:`index` is a fresh slice
+        of the capacity buffer)."""
         n_new = int(records.shape[0])
         if n_new == 0:
-            return self.index
+            return
         with self._mu:     # log + splice + count + tick commit atomically
             self._log_block(records, self._num_records, tick)
             self._grow(self._num_records // policy.PACK
@@ -424,7 +428,6 @@ class StreamingIndexer:
             self._stamp_tick(tick)
         _RECORDS_INDEXED.add(n_new)
         self._maybe_spill()
-        return self.index
 
     def append_many(self, records: jax.Array, *, mesh: Mesh | None = None,
                     axis: str = "data") -> policy.BitmapIndex:

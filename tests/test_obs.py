@@ -441,8 +441,8 @@ def test_maintenance_task_chains_to_submitter_context(installed_tracer,
                for s in spans)
 
 
-INGEST_CHILDREN = ("ingest.upload", "ingest.create", "ingest.splice",
-                   "ingest.wait", "ingest.readback")
+INGEST_CHILDREN = ("ingest.upload", "ingest.create", "ingest.wait",
+                   "ingest.splice", "ingest.count")
 
 
 @pytest.mark.parametrize("rows,traced", [(512, True), (0, True),
@@ -451,8 +451,8 @@ INGEST_CHILDREN = ("ingest.upload", "ingest.create", "ingest.splice",
 def test_append_encoded_span_tree(rows, traced):
     """One ``append_encoded`` with records is one ``ingest.append`` whose
     five children follow one another inside it, in order; an empty block
-    records nothing; the readback counter counts whether or not a tracer
-    is installed."""
+    records nothing; no append reads its counts back, whether or not a
+    tracer is installed."""
     reads = itertools.count()
     tracer = obs_trace.Tracer(clock=lambda: float(next(reads)))
     if traced:
@@ -467,7 +467,7 @@ def test_append_encoded_span_tree(rows, traced):
         assert db.append_encoded(enc) == rows
     finally:
         obs_trace.uninstall(tracer)
-    assert counter.value - before == (1 if rows else 0)
+    assert counter.value - before == 0
     spans = tracer.spans()
     if not (rows and traced):
         assert spans == []

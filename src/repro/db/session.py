@@ -58,6 +58,18 @@ _INGEST_READBACKS = obs_metrics.GLOBAL.counter(
     "db_ingest_readbacks_total",
     "blocking device-to-host reads of the per-key counts that "
     "append_encoded keeps on the device")
+_INGEST_NARROW_UPLOADS = obs_metrics.GLOBAL.counter(
+    "db_ingest_narrow_uploads_total",
+    "host record blocks append_encoded sent to the device at the "
+    "session's narrow width instead of int32")
+
+#: unsigned widths a host block may cross to the device at, narrowest
+#: first: a session takes the first that holds every key id it has
+_NARROW_DTYPES = (np.dtype(np.uint8), np.dtype(np.uint16))
+
+#: rows range-checked and then cast per step, so that the cast reads the
+#: chunk from cache: 4,096 rows of 32 int32 words are 512 KiB
+_NARROW_CHUNK_ROWS = 4096
 
 #: blocks ``append_encoded`` leaves unfinished on the device.  Two let
 #: block k+1's host-to-device copy run under block k's build, while the
@@ -83,6 +95,35 @@ def include_exclude_pred(include: Sequence[int] = (),
             "expression (col(...) == value) or an engine predicate "
             "(key(i) & ~key(j))", DeprecationWarning, stacklevel=3)
     return planner.from_include_exclude(include, exclude)
+
+
+def _narrow_dtype(num_keys: int) -> np.dtype | None:
+    """The narrowest unsigned dtype that holds every key id below
+    ``num_keys``, or None when none of ``_NARROW_DTYPES`` does."""
+    return next((dt for dt in _NARROW_DTYPES
+                 if num_keys <= 1 << (8 * dt.itemsize)), None)
+
+
+def _narrowed(records: np.ndarray,
+              dtype: np.dtype | None) -> np.ndarray | None:
+    """int32 ``records`` cast to ``dtype`` when every word lies in
+    [0, 2^bits) of it, else None (the block then crosses as int32, as it
+    does in a session without a narrow dtype).
+
+    One pass: each chunk's maximum over a uint32 view (a negative word
+    reads as 2^31 or more) decides, and the cast reads the chunk again
+    from cache."""
+    if dtype is None:
+        return None
+    top = np.iinfo(dtype).max
+    words = records.view(np.uint32)
+    out = np.empty(records.shape, dtype)
+    for s in range(0, records.shape[0], _NARROW_CHUNK_ROWS):
+        chunk = words[s:s + _NARROW_CHUNK_ROWS]
+        if chunk.max(initial=0) > top:
+            return None
+        out[s:s + _NARROW_CHUNK_ROWS] = chunk
+    return out
 
 
 def _popcounts(packed) -> np.ndarray:
@@ -125,6 +166,9 @@ class BitmapDB:
         self.path = path
         m = schema.num_keys if schema is not None else int(num_keys)
         self._keys = jnp.arange(m, dtype=jnp.int32)
+        # host record blocks cross to the device at this width when their
+        # words allow it (see append_encoded)
+        self._narrow = _narrow_dtype(m)
         if device is not None:
             # pin the session to one device: index creation, the capacity
             # buffer and every query dispatch follow the committed keys
@@ -327,6 +371,12 @@ class BitmapDB:
         """Stream pre-encoded key-word records (N, W): each int word is a
         global key id (words outside [0, num_keys) match no key).
 
+        A host block whose words all lie in [0, 2^bits) of the session's
+        narrow dtype (uint8 up to 256 keys, uint16 up to 65,536) crosses
+        to the device at that width and is widened to int32 there; any
+        other block, and a device array, goes as int32.  The WAL logs the
+        int32 records either way.
+
         Returns once the block's copy, index creation and splice are
         dispatched, leaving at most ``_INFLIGHT_BLOCKS`` blocks unfinished
         on the device: the next block's copy runs under this block's
@@ -336,7 +386,8 @@ class BitmapDB:
         reads them.
 
         Traced, a block is one ``ingest.append`` span whose children run
-        in order: ``ingest.upload`` (host-to-device copy),
+        in order: ``ingest.upload`` (range check, narrowing and
+        host-to-device copy; attribute ``bytes``, the bytes sent),
         ``ingest.create`` (index creation dispatched), ``ingest.wait``
         (the device finishes the block ``_INFLIGHT_BLOCKS`` back, before
         this splice allocates its output), ``ingest.splice`` (WAL, grow,
@@ -356,8 +407,15 @@ class BitmapDB:
         with maybe_span("ingest.append", records=shape[0],
                         backend=self._create_backend):
             with maybe_span("ingest.upload") as sp:
-                dev = jnp.asarray(records, jnp.int32)
+                narrow = (None if isinstance(records, jax.Array)
+                          else _narrowed(records, self._narrow))
+                if narrow is None:
+                    dev = jnp.asarray(records, jnp.int32)
+                else:
+                    dev = jnp.asarray(narrow)
+                    _INGEST_NARROW_UPLOADS.inc()
                 if sp is not None:
+                    sp.attrs["bytes"] = dev.nbytes
                     # the copy returns before its DMA ends: traced, the
                     # span waits for it, so the copy is not timed as wait
                     dev.block_until_ready()

@@ -5,7 +5,9 @@ A :class:`Backend` pairs the two primitive operations the engine needs:
   * ``create_index(records (N, W) int, keys (M,) int)``
       -> key-major packed bitmap (M, ceil(N/32)) uint32, with all pad bits
       past N guaranteed zero (the canonical sentinel policy ensures padded
-      records match nothing);
+      records match nothing).  Records may arrive narrowed (uint8 or
+      uint16, as ``BitmapDB.append_encoded`` uploads host blocks whose
+      words fit) and are widened to int32 on the device;
   * ``query(rows (K, Nw) uint32, invert (K,) int)``
       -> (result row (Nw,) uint32, popcount) for AND_k (invert_k ? ~r : r).
       Tail bits past the logical record count are NOT masked here — the

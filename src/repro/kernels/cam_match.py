@@ -86,7 +86,7 @@ def _cam_match_kernel(keys_ref, x_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block_w", "block_m", "interpret"))
 def cam_match(records: jax.Array, keys: jax.Array, *,
               block_w: int, block_m: int, interpret: bool) -> jax.Array:
-    """records (N, W) int32, keys (M,) int32 -> key-major packed index
+    """records (N, W) int, keys (M,) int32 -> key-major packed index
     (M, N/32) uint32: bit ``n % 32`` of word ``[m, n // 32]`` is set when
     record ``n`` holds key ``m``.
 

@@ -133,14 +133,18 @@ def create_index(records: jax.Array, keys: jax.Array, *,
     against keys and writes key-major words, the kernel realization of
     Fig. 3 of the paper.  Records pad with the record sentinel and keys
     with the key sentinel, so padding matches nothing; equals
-    ``ref.create_index`` on 32-aligned shapes.
+    ``ref.create_index`` on 32-aligned shapes.  Records of any integer
+    dtype are widened to int32 on the device: inside the kernel's jit
+    when they need no pad rows, else by the pad.
     """
     if interpret is None:
         interpret = interpret_mode()
     n = records.shape[0]
     (m,) = keys.shape
     block_w, np_, block_m, mp = _create_blocks(n, m)
-    out = _cm.cam_match(pad_records(records, np_), pad_keys(keys, mp),
+    if np_ != n:
+        records = pad_records(records, np_)
+    out = _cm.cam_match(records, pad_keys(keys, mp),
                         block_w=block_w, block_m=block_m,
                         interpret=interpret)
     return out[:m, :-(-n // PACK)]

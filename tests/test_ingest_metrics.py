@@ -1,5 +1,6 @@
 """The session layer's benchmark metrics (``bench/metrics/upload_ms.py``,
-``readback_ms.py``, ``host_gap_ms.py``, ``readbacks_per_block.py``) on the
+``readback_ms.py``, ``host_gap_ms.py``, ``readbacks_per_block.py``,
+``narrow_uploads_per_block.py``) on the
 CPU: a tiny traced ``bic-paper.load`` run, a program without the ingest
 spans and counter, and the exact idle-time intersection of
 ``host_gap_ms``."""
@@ -23,7 +24,7 @@ TINY_LOAD = (dict(block_records=2048, session_records=8192,
 def test_ingest_metrics_read_a_traced_load():
     """A tiny traced load run (no profiler: the CPU has no device trace)
     gives the session's span and counter metrics; the pipelined append
-    makes no readback."""
+    makes no readback, and every block of 8-bit words crosses as uint8."""
     sizes, mix = TINY_LOAD
     tracer = obs_trace.install(obs_trace.Tracer())
     try:
@@ -42,17 +43,19 @@ def test_ingest_metrics_read_a_traced_load():
         trace=None, peaks=None)
     got = {m: harness.load_metric(m).read(ctx)
            for m in ("upload_ms", "readback_ms", "readbacks_per_block",
-                     "host_gap_ms", "append_ms")}
+                     "host_gap_ms", "append_ms", "narrow_uploads_per_block")}
     gen.release()
     # the append reads no counts back: no readback span, no readback
     assert got["upload_ms"] > 0 and got["readback_ms"] is None
     assert got["upload_ms"] < got["append_ms"]
     assert got["readbacks_per_block"] == 0.0
+    assert got["narrow_uploads_per_block"] == 1.0
     assert got["host_gap_ms"] is None
 
 
 @pytest.mark.parametrize("name", ["upload_ms", "readback_ms", "host_gap_ms",
-                                  "readbacks_per_block"])
+                                  "readbacks_per_block",
+                                  "narrow_uploads_per_block"])
 def test_ingest_metrics_read_nothing_without_their_spans(name):
     """A program without the ingest spans and counter (or a run without a
     trace) gives no reading, and no error."""
@@ -60,6 +63,22 @@ def test_ingest_metrics_read_nothing_without_their_spans(name):
         gen=type("G", (), {"blocks_done": 3}), window=(0.0, 1.0),
         spans=[], counters={}, compiles=0, trace=None, peaks=None)
     assert harness.load_metric(name).read(ctx) is None
+
+
+@pytest.mark.parametrize("name, counter", [
+    ("readbacks_per_block", "db_ingest_readbacks_total"),
+    ("narrow_uploads_per_block", "db_ingest_narrow_uploads_total")])
+def test_counter_metrics_are_the_counter_over_the_blocks(name, counter):
+    """The counter's change in the window over the blocks appended in it;
+    no reading without blocks."""
+    gen = type("G", (), {"blocks_done": 8})
+    ctx = harness.LayerContext(
+        gen=gen, window=(0.0, 1.0), spans=[], counters={counter: 6},
+        compiles=0, trace=None, peaks=None)
+    metric = harness.load_metric(name)
+    assert metric.read(ctx) == 0.75
+    gen.blocks_done = 0
+    assert metric.read(ctx) is None
 
 
 def _span(name, t0, t1):

@@ -1,6 +1,7 @@
 """The pipelined ``BitmapDB.append_encoded``: per-key counts kept on the
 device and read only by ``stats``, at most ``_INFLIGHT_BLOCKS`` blocks
 left unwaited, the int32 accumulator folded before it could overflow,
+host blocks uploaded at the session's narrow width when their words fit,
 and durability and visibility as before."""
 import os
 
@@ -13,6 +14,7 @@ from repro.db import session as session_mod
 from repro.engine import runtime
 from repro.engine.planner import key
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 M = 16
 #: unaligned sizes, so splices shift and carry across words
@@ -137,3 +139,124 @@ def test_durable_append_is_in_the_wal_without_a_device_read(tmp_path,
     np.testing.assert_array_equal(wal[0][1], host)
     np.testing.assert_array_equal(wal[1][1], np.asarray(dev))
     assert wal[0][1].dtype == wal[1][1].dtype == np.int32
+
+
+def _numpy_index(recs, m):
+    """Key-major packed rows (m, ceil(n/32)) uint32 of ``recs``."""
+    hits = np.stack([(recs == k).any(axis=1) for k in range(m)])
+    hits = np.pad(hits, ((0, 0), (0, -len(recs) % 32)))
+    return np.packbits(hits, axis=1, bitorder="little").view("<u4")
+
+
+def _upload_bytes(tracer):
+    return [sp.attrs["bytes"] for sp in tracer.spans()
+            if sp.name == "ingest.upload"]
+
+
+#: (low, high) of a block's words, and a word planted in block 1 that
+#: sends it as int32: words in [M, 256) still cross as uint8 and match
+#: no key, a negative word or one of 256 or more does not fit uint8
+NARROW_CASES = {"keys": (0, M, None), "above_keys": (0, 256, None),
+                "negative": (0, M, -3), "sentinel": (0, M, -1),
+                "wide": (0, M, 256), "wider": (0, M, 70_000)}
+
+
+@pytest.mark.parametrize("case", sorted(NARROW_CASES))
+@pytest.mark.parametrize("backend", ["ref", "bulk", "pallas"])
+def test_narrowed_and_int32_uploads_build_the_same_index(monkeypatch,
+                                                         backend, case):
+    """A host block whose words all fit uint8 crosses as uint8 (counted,
+    1 byte a word); a block with a word outside [0, 256) crosses as
+    int32, exactly as a session that never narrows; both build the index
+    NumPy builds."""
+    low, high, planted = NARROW_CASES[case]
+    counter = obs_metrics.GLOBAL.counter("db_ingest_narrow_uploads_total")
+    rng = np.random.default_rng(27)
+    blocks = [rng.integers(low, high, (n, 3), dtype=np.int32)
+              for n in BLOCKS[:3]]
+    if planted is not None:
+        blocks[1][7, 2] = planted
+    narrow = BitmapDB(num_keys=M, backend=backend)
+    assert narrow._narrow == np.uint8
+    tracer = obs_trace.install(obs_trace.Tracer())
+    try:
+        before = counter.value
+        for b in blocks:
+            narrow.append_encoded(b)
+        sent = counter.value - before
+    finally:
+        obs_trace.uninstall(tracer)
+    with monkeypatch.context() as mp:
+        mp.setattr(session_mod, "_NARROW_DTYPES", ())
+        wide = BitmapDB(num_keys=M, backend=backend)
+    assert wide._narrow is None
+    before = counter.value
+    for b in blocks:
+        wide.append_encoded(b)
+    assert counter.value == before
+    fits = [planted is None or i != 1 for i in range(len(blocks))]
+    assert sent == sum(fits)
+    assert _upload_bytes(tracer) == [b.size * (1 if f else 4)
+                                     for b, f in zip(blocks, fits)]
+    got = np.asarray(narrow.index.packed)
+    np.testing.assert_array_equal(got, np.asarray(wide.index.packed))
+    np.testing.assert_array_equal(got, _numpy_index(np.concatenate(blocks),
+                                                    M))
+    assert narrow.stats.counts == wide.stats.counts
+
+
+@pytest.mark.parametrize("num_keys, dtype", [
+    (1, np.uint8), (256, np.uint8), (257, np.uint16), (1_795, np.uint16),
+    (65_536, np.uint16), (65_537, None)])
+def test_a_session_narrows_to_the_least_width_of_its_keys(num_keys, dtype):
+    assert session_mod._narrow_dtype(num_keys) == dtype
+
+
+def test_a_1795_key_session_uploads_uint16():
+    """SSB's 1,795 key rows: words up to 65,535 cross as uint16 (2 bytes a
+    word), and those at or past 1,795 match no key."""
+    m = 1_795
+    counter = obs_metrics.GLOBAL.counter("db_ingest_narrow_uploads_total")
+    rng = np.random.default_rng(28)
+    recs = rng.integers(0, m, (70, 4), dtype=np.int32)
+    recs[::5, 0] = rng.integers(m, 1 << 16, 14)
+    db = BitmapDB(num_keys=m, backend="ref")
+    assert db._narrow == np.uint16
+    tracer = obs_trace.install(obs_trace.Tracer())
+    try:
+        before = counter.value
+        db.append_encoded(recs)
+        assert counter.value == before + 1
+    finally:
+        obs_trace.uninstall(tracer)
+    assert _upload_bytes(tracer) == [recs.size * 2]
+    np.testing.assert_array_equal(np.asarray(db.index.packed),
+                                  _numpy_index(recs, m))
+
+
+def test_a_narrowed_durable_append_logs_int32_and_recovers(tmp_path):
+    """The WAL holds the caller's int32 records, not the uint8 upload, and
+    recovery rebuilds the same index from it."""
+    counter = obs_metrics.GLOBAL.counter("db_ingest_narrow_uploads_total")
+    rng = np.random.default_rng(29)
+    blocks = [rng.integers(0, 256, (n, 3), dtype=np.int32)
+              for n in BLOCKS[:4]]
+    db = BitmapDB(num_keys=M, path=str(tmp_path), backend="ref",
+                  spill_records=None)
+    before = counter.value
+    for b in blocks:
+        db.append_encoded(b)
+    assert counter.value == before + len(blocks)
+    wal = db.store.replay_wal()
+    assert [start for start, _, _ in wal] == list(
+        np.cumsum([0, *BLOCKS[:3]]))
+    for (_, logged, _), b in zip(wal, blocks):
+        assert logged.dtype == np.int32
+        np.testing.assert_array_equal(logged, b)
+    want = np.asarray(db.index.packed)
+    del db
+    back = BitmapDB.open(str(tmp_path), num_keys=M, backend="ref",
+                         spill_records=None)
+    np.testing.assert_array_equal(np.asarray(back.index.packed), want)
+    np.testing.assert_array_equal(want, _numpy_index(np.concatenate(blocks),
+                                                     M))

@@ -146,6 +146,22 @@ def test_create_index_runs_in_jit_cam_match(one_chip):
                      re.MULTILINE)
 
 
+@pytest.mark.parametrize("dtype", [jnp.uint8, jnp.uint16])
+def test_narrowed_records_widen_inside_jit_cam_match(one_chip, dtype):
+    """Records that cross to the chip narrowed (``BitmapDB.append_encoded``
+    at 256 keys sends uint8) reach ``jit_cam_match`` as they are, so the
+    widening to int32 is part of its relayout and no dispatch of its own,
+    and the pipeline compiles at the benchmark's block."""
+    fn = functools.partial(ops.create_index, interpret=False)
+    args = (_spec((BLOCK, W), dtype, one_chip),
+            _spec((256,), jnp.int32, one_chip))
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    records = jaxpr.invars[0]
+    uses = [e for e in jaxpr.eqns if records in e.invars]
+    assert [e.params.get("name") for e in uses] == ["cam_match"]
+    assert _kernels(_compile(fn, *args)) == 1
+
+
 def test_pallas_bucket_executor_compiles_for_v5e(one_chip):
     """The ``pallas`` backend's serving path: the fused query kernel
     vmapped over every pass of a bucket."""
